@@ -230,11 +230,15 @@ def bench_convergence(ccfg, tcfg):
 
 def _run_sub(code: str, n_devices: int = 4, timeout: int = 900):
     """Run a benchmark snippet in a subprocess with its own fake-device
-    XLA_FLAGS (the parent already initialized jax with 1 device)."""
+    XLA_FLAGS (the parent already initialized jax with 1 device). The
+    child counts collectives and wire bytes on fake CPU devices, so it
+    is pinned to the CPU: it must never reach for a chip the parent
+    holds."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
@@ -251,7 +255,7 @@ def bench_collectives():
     rows = _run_sub("""
         import time
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -259,7 +263,7 @@ def bench_collectives():
 
         def timed(fn, x, n=20):
             f = jax.jit(shard_map(fn, mesh=mesh, in_specs=P(),
-                                  out_specs=P(), check_rep=False))
+                                  out_specs=P(), check_vma=False))
             jax.block_until_ready(f(x))
             t0 = time.perf_counter()
             for _ in range(n):
@@ -298,7 +302,7 @@ def bench_collectives():
 
         with collective_trace() as log_f:
             jax.jit(shard_map(fused, mesh=mesh, in_specs=P(),
-                              out_specs=P(), check_rep=False)
+                              out_specs=P(), check_vma=False)
                     ).lower(tree)
         us_n = timed(per_node, tree)
         us_f = timed(fused, tree)

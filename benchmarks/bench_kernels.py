@@ -94,7 +94,7 @@ def bench_psparse(metrics: dict) -> list[tuple]:
     psi0 = jax.random.normal(ks[2], (k0,))
     proj0 = init_psparse_projections(ks[3], T0, k0, 0.1)
     got = psparse_update(a0, s0, s0, s0, proj0.params, psi0,
-                         beta=0.9, m=proj0.m, interpret=True)
+                         beta=0.9, m=proj0.m)
     want = psparse_update_ref(a0, s0, s0, s0, proj0.params, psi0,
                               beta=0.9, m=proj0.m)
     bitwise = all(bool((g == w).all()) for g, w in zip(got, want))

@@ -23,6 +23,9 @@ def _run(name, main_fn):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_cifar_hybrid, bench_countsketch, bench_factored_grad,
         bench_kernels, bench_memory_complexity, bench_mnist,

@@ -44,16 +44,16 @@ RESIDUAL_LAYOUT = "per_worker_v1"
 def gather_per_worker(tree, mesh, axis_name):
     """Stack every DP worker's device-local copy of `tree`'s leaves on
     a NEW leading (W, ...) axis. The per-worker buffers live under a
-    replicated spec (check_rep=False), so a plain host copy would
+    replicated spec (check_vma=False), so a plain host copy would
     silently keep worker 0's buffer and drop the rest — this makes the
     decomposition explicit before it leaves the devices."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fn = shard_map(
         lambda t: jax.tree.map(lambda x: x[None], t),
         mesh=mesh, in_specs=P(), out_specs=P(axis_name),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)(tree)
 
 
@@ -61,7 +61,7 @@ def scatter_per_worker(stacked, mesh, axis_name):
     """Inverse of `gather_per_worker`: each worker takes its own row of
     the replicated (W, ...) stacked leaves — exact restore of the
     per-worker decomposition (no mass redistribution)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def _take(t):
@@ -71,7 +71,7 @@ def scatter_per_worker(stacked, mesh, axis_name):
                                                    keepdims=False), t)
 
     fn = shard_map(_take, mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return jax.jit(fn)(stacked)
 
 

@@ -60,10 +60,13 @@ def _kernel(vec_ref, par_ref, tin_ref, out_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("blk", "interpret"))
 def csvec_insert(table, params, vec, *,
-                 blk: int = DEFAULT_BLK, interpret: bool = True):
+                 blk: int = DEFAULT_BLK, interpret: bool | None = None):
     """table (r, c) f32; params (4, r) u32; vec (n,) — returns the
     accumulated (r, c) table. Matches `countsketch.csvec.insert` on the
     shared hash family (parity tested in tests/test_countsketch.py)."""
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     r, c = table.shape
     log2c = c.bit_length() - 1
     assert c == (1 << log2c), f"cols must be a power of two, got {c}"

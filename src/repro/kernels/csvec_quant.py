@@ -58,13 +58,16 @@ def _kernel(tab_ref, q_ref, scale_ref, dhat_ref, resid_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def csvec_quant(table, *, interpret: bool = True):
+def csvec_quant(table, *, interpret: bool | None = None):
     """table (r, c) f32 -> (q (r, c) i8, scale (r,) f32,
     dhat (r, c) f32, resid (r, c) f32), all from one VMEM-resident pass.
 
     Matches `countsketch.csvec.quantize_table` / `dequantize_table` /
     `quantize_residual` bit-for-bit.
     """
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     r, c = table.shape
     q, scale, dhat, resid = pl.pallas_call(
         _kernel,

@@ -197,11 +197,14 @@ def _kernel(par_ref, tab_ref, val_ref, idx_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("dim", "k", "chunk", "interpret"))
 def csvec_topk(table, params, *, dim: int, k: int,
-               chunk: int = DEFAULT_CHUNK, interpret: bool = True):
+               chunk: int = DEFAULT_CHUNK, interpret: bool | None = None):
     """table (r, c) f32; params (4, r) u32; returns (vals (k,) f32,
     idx (k,) i32) — the top-k coordinates of the sketched vector by
     |median-of-r estimate|, descending, peak memory O(chunk + k).
     Matches `countsketch.csvec.topk_streaming` (parity tested)."""
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     r, c = table.shape
     log2c = c.bit_length() - 1
     assert c == (1 << log2c), f"cols must be a power of two, got {c}"

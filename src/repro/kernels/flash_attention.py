@@ -81,8 +81,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: int | None = None,
                     q_blk: int = 256, kv_blk: int = 256,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q (B, Hq, S, D); k/v (B, Hkv, S, D) -> (B, Hq, S, D)."""
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]
     G = Hq // Hkv

@@ -72,9 +72,12 @@ def _kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, h_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunk(q, k, v, li, lf, *, chunk: int = 256,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """q,k (B,H,S,Dk); v (B,H,S,Dv); li,lf (B,H,S) log gates.
     Returns h (B,H,S,Dv), (C (B,H,Dk,Dv), n (B,H,Dk), m (B,H))."""
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     B, H, S, Dk = q.shape
     Dv = v.shape[-1]
     W = min(chunk, S)

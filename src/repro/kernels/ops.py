@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 `use_pallas(True)` routes model hot spots through the TPU kernels; the
-default (False) keeps XLA-native implementations — the right choice on
-this CPU container where interpret-mode kernels would dominate runtime.
-On real TPU hardware the kernels compile via Mosaic (interpret=False).
+default (False) keeps XLA-native implementations. Whether a kernel
+runs compiled (Mosaic) or in the Pallas interpreter follows one rule,
+`interpret_mode()`: every kernel whose ``interpret`` argument is left
+as None asks it when it is traced, so importing this package touches
+no backend and no kernel runs interpreted on a TPU.
 """
 from __future__ import annotations
 
@@ -18,7 +20,6 @@ from repro.kernels.psparse_update import psparse_update
 from repro.kernels.ring_allreduce import ring_allreduce, ring_allreduce_ref
 from repro.kernels.sketch_update import sketch_update
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 _FLAGS = {"use_pallas": False}
 
 
@@ -31,8 +32,9 @@ def pallas_enabled() -> bool:
 
 
 def interpret_mode() -> bool:
-    """Interpret on CPU (validation), compiled Mosaic on TPU (target)."""
-    return not _ON_TPU
+    """Interpret off the TPU (CPU validation), compiled Mosaic on it.
+    Reads the default backend when called, never at import."""
+    return jax.default_backend() != "tpu"
 
 
 __all__ = [

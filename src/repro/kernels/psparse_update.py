@@ -248,7 +248,7 @@ def _kernel(a_ref, par_ref, psi_ref, x_in_ref, y_in_ref, z_in_ref,
 )
 def psparse_update(a, x_s, y_s, z_s, params, psi, *, beta: float,
                    m: int, t_blk: int = DEFAULT_T_BLK,
-                   d_blk: int = DEFAULT_D_BLK, interpret: bool = True):
+                   d_blk: int = DEFAULT_D_BLK, interpret: bool | None = None):
     """Fused psparse EMA update. a (T, d); sketches (d, k); params
     (3, 4) uint32; psi (k,) pre-masked. Same padding contract as
     `kernels.sketch_update`: k is padded to the 128-lane width, ragged
@@ -256,6 +256,9 @@ def psparse_update(a, x_s, y_s, z_s, params, psi, *, beta: float,
     generated tile rows beyond T are irrelevant against them), outputs
     match the input sketch shapes. Column masking is the caller's.
     """
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     T, d = a.shape
     k = x_s.shape[1]
     t_blk = min(t_blk, T)

@@ -256,12 +256,8 @@ def ring_allreduce(
     kwargs = {}
     if not interpret:
         # real Mosaic needs the collective_id for the cross-device sems
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                collective_id=0)
-        except AttributeError:  # older jax spelling
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                collective_id=0)
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            collective_id=0)
     y, res = pl.pallas_call(
         kern, out_shape=out_shape, scratch_shapes=scratch,
         interpret=interpret, **kwargs)(xp)

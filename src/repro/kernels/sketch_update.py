@@ -59,7 +59,7 @@ def _kernel(a_ref, ups_ref, omg_ref, phi_ref, psi_ref,
 )
 def sketch_update(a, x_s, y_s, z_s, ups, omg, phi, psi, *,
                   beta: float, t_blk: int = DEFAULT_T_BLK,
-                  d_blk: int = DEFAULT_D_BLK, interpret: bool = True):
+                  d_blk: int = DEFAULT_D_BLK, interpret: bool | None = None):
     """Fused EMA update. a (T, d); sketches (d, k); proj (T, k); psi (k,).
 
     k is padded to a multiple of 128 internally; ragged T/d are padded
@@ -67,6 +67,9 @@ def sketch_update(a, x_s, y_s, z_s, ups, omg, phi, psi, *,
     nothing to the contraction; padded d rows are sliced off). Outputs
     match the input sketch shapes exactly.
     """
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
     T, d = a.shape
     k = x_s.shape[1]
     t_blk = min(t_blk, T)

@@ -32,9 +32,11 @@ from repro.configs import (
     SHAPES, ARCHS, cell_is_runnable, get_arch, input_specs,
 )
 from repro.launch.hlo_analysis import analyze_hlo_text
-from repro.launch.mesh import make_production_mesh, rules_for_mesh
+from repro.launch.mesh import make_mesh, rules_for_mesh
 from repro.models.transformer import SketchSettings, abstract_cache
-from repro.parallel.sharding import param_shardings, use_rules
+from repro.parallel.sharding import (
+    cache_shardings, param_shardings, use_rules,
+)
 from repro.serve.engine import make_decode_step, make_prefill_step
 from repro.train.state import RunConfig, abstract_train_state
 from repro.train.step import make_train_step
@@ -180,52 +182,6 @@ def build_cell(cfg, shape, rules, *, sketched: bool = True,
     return fn, args, shs, (1,)
 
 
-# cache leaf name -> rank WITHOUT the leading stacked-groups dim
-_CACHE_RANKS = {
-    "k": 4, "v": 4,                     # (B, KV, C, D)
-    "C": 4, "m_n": 3, "m_m": 2,         # mLSTM (B,H,Dk,Dv)/(B,H,Dk)/(B,H)
-    "conv": 3,                          # (B, W-1, F)
-    "s_c": 2, "s_n": 2, "s_m": 2, "s_h": 2,   # sLSTM (B, units)
-    "r_h": 2,                           # RG-LRU (B, lru)
-}
-
-
-def cache_shardings(cache, cfg, rules):
-    """Decode-cache layout (DESIGN.md §4): batch over dp everywhere;
-    attention KV caches head-sharded when KV >= TP, else sequence-sharded
-    over the model axis (flash-decoding merge); recurrent states sharded
-    on their feature dim."""
-    mesh, dp, tp = rules.mesh, rules.dp, rules.tp_axis
-    dp_size, tp_size = rules.dp_size, rules.tp_size
-
-    def spec(path, leaf):
-        name = None
-        for part in reversed(path):
-            key = getattr(part, "key", None)
-            if isinstance(key, str):
-                name = key
-                break
-        shp = leaf.shape
-        axes = [None] * len(shp)
-        rank = _CACHE_RANKS.get(name)
-        if rank is None or len(shp) < rank:
-            return NamedSharding(mesh, P(*axes))
-        lead = len(shp) - rank            # 1 when group-stacked, else 0
-        b = lead                          # batch dim index
-        if shp[b] % dp_size == 0:
-            axes[b] = dp
-        if name in ("k", "v"):
-            if cfg.num_kv_heads >= tp_size and shp[b + 1] % tp_size == 0:
-                axes[b + 1] = tp          # kv-head sharded
-            elif shp[b + 2] % tp_size == 0:
-                axes[b + 2] = tp          # sequence-sharded cache
-        elif shp[-1] % tp_size == 0:      # feature dim of recurrent state
-            axes[-1] = tp
-        return NamedSharding(mesh, P(*axes))
-
-    return jax.tree_util.tree_map_with_path(spec, cache)
-
-
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              *, save_hlo: bool = True, sketched: bool = True,
              variant: str = "base", out_dir: str = OUT_DIR) -> dict:
@@ -239,7 +195,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         rec.update(status="skipped", reason=why)
         return rec
     try:
-        mesh = make_production_mesh(multi_pod=multi_pod)
+        mesh = make_mesh(16, 16, n_pod=2 if multi_pod else 1)
         rules = rules_for_mesh(mesh, strategy=variant_strategy(variant))
         with use_rules(rules), mesh:
             fn, args, shardings, donate = build_cell(

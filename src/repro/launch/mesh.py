@@ -1,28 +1,36 @@
-"""Production mesh construction (functions only — importing this module
-never touches jax device state)."""
+"""Mesh construction over the devices present (functions only —
+importing this module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """(16,16) data x model single pod; (2,16,16) pod x data x model for
-    the 2-pod = 512-chip configuration. The pod axis composes with data
-    for batch sharding so the lowest-bandwidth (inter-pod DCI) axis only
-    carries gradient reduce-scatter traffic (DESIGN.md §4)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+def make_mesh(n_data: int, n_model: int, *, n_pod: int = 1):
+    """(data, model) mesh — (pod, data, model) when ``n_pod > 1`` — over
+    the first ``n_pod * n_data * n_model`` devices present; raises when
+    fewer are present. Every axis is Auto: `parallel/sharding.py`
+    places activations with `with_sharding_constraint`, which refers
+    only to Auto axes. The pod axis composes with data for batch
+    sharding so the lowest-bandwidth axis only carries gradient
+    reduce-scatter traffic (DESIGN.md §4)."""
+    shape, axes = (n_data, n_model), ("data", "model")
+    if n_pod > 1:
+        shape, axes = (n_pod,) + shape, ("pod",) + axes
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_debug_mesh(n_data: int = 2, n_model: int = 4,
-                    *, multi_pod: bool = False):
-    """Small mesh for CI-scale sharding tests (needs
-    xla_force_host_platform_device_count >= n_data*n_model*(pods))."""
-    if multi_pod:
-        return jax.make_mesh((2, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+def host_mesh(n_model: int | None = None):
+    """(data, model) mesh over every device present: ``n_model`` devices
+    per model group (all of them when None), the rest on data."""
+    n = len(jax.devices())
+    n_model = n if n_model is None else n_model
+    if n_model < 1 or n % n_model:
+        raise ValueError(
+            f"model axis of {n_model} does not divide the {n} devices "
+            f"present")
+    return make_mesh(n // n_model, n_model)
 
 
 def dp_axes_for_mesh(mesh) -> tuple[str, ...]:

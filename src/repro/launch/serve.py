@@ -2,8 +2,11 @@
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python -m repro.launch.serve \
-        --arch tinyllama-1.1b --reduced --debug-mesh \
+        --arch tinyllama-1.1b --reduced --tp 4 \
         --num-prompts 4 --max-new 8
+
+``--tp M`` serves from a (devices/M data, M model) mesh over every
+device present; without it the engine runs on one device.
 
 Live monitoring (DESIGN.md §11): ``--monitor`` threads activation
 sketches through the jitted serve steps and prints pathology flags;
@@ -15,11 +18,9 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import get_arch, reduced as reduce_cfg
-from repro.launch.dryrun import cache_shardings
-from repro.launch.mesh import (
-    make_debug_mesh, make_production_mesh, rules_for_mesh,
-)
+from repro.launch.mesh import host_mesh, rules_for_mesh
 from repro.models.transformer import init_params
 from repro.parallel.sharding import param_shardings, use_rules
 from repro.serve.engine import ServeEngine
@@ -30,8 +31,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--debug-mesh", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--tp", type=int, default=0, metavar="M",
+                    help="serve from a (devices/M, M) data x model mesh "
+                         "over every device present (0: one device)")
     ap.add_argument("--num-prompts", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
@@ -42,6 +44,7 @@ def main():
     ap.add_argument("--telemetry-json", default=None, metavar="PATH",
                     help="export TelemetryRecords as JSONL")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -57,9 +60,8 @@ def main():
         monitor=args.monitor, monitor_rank=args.monitor_rank,
         telemetry_log=tlog)
 
-    if args.debug_mesh or args.multi_pod:
-        mesh = make_debug_mesh(2, 4) if args.debug_mesh \
-            else make_production_mesh(multi_pod=args.multi_pod)
+    if args.tp:
+        mesh = host_mesh(args.tp)
         rules = rules_for_mesh(mesh)
         with use_rules(rules), mesh:
             params = jax.tree.map(lambda x: x.astype(jnp.bfloat16),

@@ -1,13 +1,13 @@
 """Distributed training launcher.
 
 Composes mesh + sharding rules + sharded train state + the fault-
-tolerant loop. On this CPU container use --debug-mesh (8 fake devices via
-XLA_FLAGS); on a real cluster the same entry point runs per host under
-`jax.distributed.initialize` with the production mesh.
+tolerant loop. With more than one device (or ``--tp M``) the step runs
+on a (devices/M data, M model) mesh built from the devices present —
+M defaults to all of them; on the CPU, fake devices come from XLA_FLAGS:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python -m repro.launch.train \
-        --arch tinyllama-1.1b --reduced --debug-mesh --steps 20
+        --arch tinyllama-1.1b --reduced --tp 4 --steps 20
 
 Data-parallel shard_map with count-sketch gradient compression (the
 only cross-worker traffic is the O(r*c) sketch table + optional p2
@@ -40,9 +40,8 @@ import logging
 import jax
 
 from repro.configs import SHAPES, get_arch, reduced as reduce_cfg
-from repro.launch.mesh import (
-    make_debug_mesh, make_production_mesh, rules_for_mesh,
-)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import host_mesh, rules_for_mesh
 from repro.models.transformer import SketchSettings
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import use_rules
@@ -63,9 +62,10 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--reduced", action="store_true",
                     help="CPU-runnable reduced config")
-    ap.add_argument("--debug-mesh", action="store_true",
-                    help="(2,4) data x model mesh (needs >=8 devices)")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--tp", type=int, default=0, metavar="M",
+                    help="(devices/M, M) data x model mesh over every "
+                         "device present (default with >1 device: M = "
+                         "all devices)")
     ap.add_argument("--dp", type=int, default=0, metavar="W",
                     help="W-way data-parallel shard_map step (needs W "
                          "devices; batch must divide by W)")
@@ -133,7 +133,8 @@ def main():
                          "m = max(k_max, round(p*T)))")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="artifacts/ckpt_launch")
-    ap.add_argument("--ckpt-every", type=int, default=50)
+    ap.add_argument("--ckpt-every", type=int, default=50,
+                    help="checkpoint period in steps; 0 writes none")
     args = ap.parse_args()
 
     cfg = get_arch(args.arch)
@@ -186,6 +187,7 @@ def main():
         # combination — one structured error naming the two conflicting
         # fields (train/state.py, DESIGN.md §15)
         raise SystemExit(f"invalid flag combination: {e}")
+    enable_compile_cache()
     loop = LoopConfig(num_steps=args.steps, ckpt_every=args.ckpt_every,
                       ckpt_dir=args.ckpt_dir, log_every=10)
 
@@ -206,9 +208,8 @@ def main():
             mesh = Mesh(devs, ("data",))
         from repro.train.loop import run_training
         state, hist = run_training(cfg, run, loop, dp_mesh=mesh)
-    elif args.debug_mesh or args.multi_pod or len(jax.devices()) > 1:
-        mesh = make_production_mesh(multi_pod=args.multi_pod) \
-            if not args.debug_mesh else make_debug_mesh(2, 4)
+    elif args.tp or len(jax.devices()) > 1:
+        mesh = host_mesh(args.tp or None)
         rules = rules_for_mesh(mesh, strategy=args.strategy)
         state, hist = run_training_sharded(cfg, run, loop, mesh, rules)
     else:

@@ -196,10 +196,7 @@ def moe_apply(p: dict, x: Array, cfg, *, return_dispatch=False):
         w_specs = (P(), P(None, None, model), P(None, None, model),
                    P(None, model, None))
 
-    # jax.shard_map / check_vma only exist on newer JAX; this container
-    # pins 0.4.x where the API lives under jax.experimental with the
-    # replication check named check_rep (same semantics: disabled).
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def _local(xl, router, wg, wu, wd):
         # xl (T_loc, d) — sharded over dp, replicated over model
@@ -239,7 +236,7 @@ def moe_apply(p: dict, x: Array, cfg, *, return_dispatch=False):
     fn = partial(
         shard_map, mesh=rules.mesh,
         in_specs=(P(dp, None),) + w_specs,
-        out_specs=out_specs, check_rep=False,
+        out_specs=out_specs, check_vma=False,
     )(_local)
     out = fn(
         x.reshape(T, d), p["router"], p["we_gate"], p["we_up"], p["we_down"]

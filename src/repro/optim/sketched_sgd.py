@@ -79,7 +79,7 @@ from repro.countsketch.csvec import (
 )
 from repro.kernels.csvec_insert import csvec_insert
 from repro.kernels.csvec_topk import csvec_topk
-from repro.kernels import interpret_mode, pallas_enabled
+from repro.kernels import pallas_enabled
 
 
 def flat_dim(params) -> int:
@@ -103,8 +103,7 @@ def grad_csvec(cfg, dim: int) -> CSVec:
 
 def _sketch_residual(cs: CSVec, v, cfg):
     if pallas_enabled():
-        table = csvec_insert(cs.table, cs.params, v,
-                             interpret=interpret_mode())
+        table = csvec_insert(cs.table, cs.params, v)
         return CSVec(table=table, params=cs.params, dim=cs.dim)
     return insert(cs, v)
 
@@ -116,7 +115,7 @@ def _recover_candidates(cs: CSVec, k: int, cfg):
     so no index exchange is ever needed)."""
     if pallas_enabled():
         return csvec_topk(cs.table, cs.params, dim=cs.dim, k=k,
-                          chunk=cfg.cs_chunk, interpret=interpret_mode())
+                          chunk=cfg.cs_chunk)
     return topk_streaming(cs, k, chunk=cfg.cs_chunk)
 
 
@@ -158,8 +157,7 @@ def countsketch_local(grads, err_state, cfg) -> CountsketchLocal:
     if cfg.wire_dtype == "int8":
         if pallas_enabled():
             from repro.kernels.csvec_quant import csvec_quant
-            _, _, dhat, _ = csvec_quant(
-                cs.table, interpret=interpret_mode())
+            _, _, dhat, _ = csvec_quant(cs.table)
         else:
             q, scale = quantize_table(cs.table)
             dhat = dequantize_table(q, scale)

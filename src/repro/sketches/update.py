@@ -9,8 +9,8 @@ two functions here; no other module may inline the EMA recurrence.
   * the fused Pallas kernel ``kernels/sketch_update`` — one HBM pass over
     the activation matrix for all three contractions (DESIGN.md §7).
     Selected when ``use_kernel`` is True, or by default whenever
-    ``kernels.ops.use_pallas(True)`` is active (interpret mode on CPU,
-    Mosaic on TPU);
+    ``kernels.ops.use_pallas(True)`` is active (interpret mode off the
+    TPU, Mosaic on it: ``kernels.ops.interpret_mode``);
   * the pure-jnp reference path — bit-identical to the historical
     ``ema_node_update`` / ``sketch_update_single`` implementations, the
     default on CPU where interpret-mode Pallas would dominate runtime.
@@ -153,7 +153,6 @@ def _fused_kernel_update(x_s, y_s, z_s, a, ups, omg, ph, ps, beta,
                          k_active, axis_name):
     """Route through the fused Pallas kernel (projections pre-masked so
     the kernel's padded columns contribute zeros)."""
-    from repro.kernels.ops import interpret_mode
     from repro.kernels.sketch_update import sketch_update
 
     f32 = jnp.float32
@@ -161,7 +160,7 @@ def _fused_kernel_update(x_s, y_s, z_s, a, ups, omg, ph, ps, beta,
         xn, yn, zn = sketch_update(
             a, x_s.astype(f32), y_s.astype(f32), z_s.astype(f32),
             ups.astype(f32), omg.astype(f32), ph.astype(f32),
-            ps.astype(f32), beta=float(beta), interpret=interpret_mode())
+            ps.astype(f32), beta=float(beta))
     else:
         # DP-exact: the kernel with zero input sketches yields the pure
         # (1-beta)-scaled increment, which is psum-mergeable
@@ -169,7 +168,7 @@ def _fused_kernel_update(x_s, y_s, z_s, a, ups, omg, ph, ps, beta,
         ix, iy, iz = sketch_update(
             a, zeros, zeros, zeros,
             ups.astype(f32), omg.astype(f32), ph.astype(f32),
-            ps.astype(f32), beta=float(beta), interpret=interpret_mode())
+            ps.astype(f32), beta=float(beta))
         xn = beta * x_s.astype(f32) + jax.lax.psum(ix, axis_name)
         yn = beta * y_s.astype(f32) + jax.lax.psum(iy, axis_name)
         zn = beta * z_s.astype(f32) + jax.lax.psum(iz, axis_name)
@@ -226,7 +225,6 @@ def ema_triple_increment(
         # the fused kernel with zero input sketches yields the pure
         # (1-beta)-scaled f32 increment — same trick as the DP-exact
         # kernel branch, minus its psum
-        from repro.kernels.ops import interpret_mode
         from repro.kernels.sketch_update import sketch_update
 
         f32 = jnp.float32
@@ -234,8 +232,7 @@ def ema_triple_increment(
         return sketch_update(
             a, zeros, zeros, zeros,
             ups.astype(f32), omg.astype(f32), ph.astype(f32),
-            ps.astype(f32), beta=float(beta),
-            interpret=interpret_mode())
+            ps.astype(f32), beta=float(beta))
 
     at = a.astype(dt).T                                    # (d, T)
     aot = at if a_out is None \
@@ -294,7 +291,6 @@ def proj_triple_update(
         use_kernel = pallas_enabled()
 
     if use_kernel and a_out is None and axis_name is None:
-        from repro.kernels.ops import interpret_mode
         from repro.kernels.psparse_update import psparse_update
 
         f32 = jnp.float32
@@ -302,7 +298,7 @@ def proj_triple_update(
         xn, yn, zn = psparse_update(
             jax.lax.stop_gradient(a), x_s.astype(f32), y_s.astype(f32),
             z_s.astype(f32), proj.params, ps, beta=float(beta),
-            m=proj.m, interpret=interpret_mode())
+            m=proj.m)
         dt = x_s.dtype
         return tuple(mask_columns(o.astype(dt), k_active)
                      for o in (xn, yn, zn))
@@ -354,7 +350,6 @@ def proj_triple_increment(
     if use_kernel:
         # zero input sketches -> the pure (1-beta)-scaled increment,
         # same trick as the dense kernel branch
-        from repro.kernels.ops import interpret_mode
         from repro.kernels.psparse_update import psparse_update
 
         f32 = jnp.float32
@@ -362,7 +357,7 @@ def proj_triple_increment(
         zeros = jnp.zeros(x_s.shape, f32)
         ix, iy, iz = psparse_update(
             jax.lax.stop_gradient(a), zeros, zeros, zeros, proj.params,
-            ps, beta=float(beta), m=proj.m, interpret=interpret_mode())
+            ps, beta=float(beta), m=proj.m)
     else:
         from repro.kernels.psparse_update import psparse_triple_increment
 

@@ -57,7 +57,8 @@ def _refresh_sketch(sketch):
 @dataclasses.dataclass
 class LoopConfig:
     num_steps: int = 100
-    ckpt_every: int = 50
+    ckpt_every: int = 50              # 0: write no checkpoint (a
+    #                                   fresh ckpt_dir then resumes none)
     ckpt_dir: str = "artifacts/ckpt"
     ckpt_keep: int = 3
     straggler_factor: float = 3.0
@@ -81,15 +82,23 @@ def run_training(cfg, run: RunConfig, loop: LoopConfig, *,
     pipe = PipelineConfig(seed=seed, global_batch=run.global_batch,
                           seq_len=run.seq_len, vocab=cfg.vocab_size)
     ckpt = Checkpointer(loop.ckpt_dir, keep=loop.ckpt_keep)
-    state = init_train_state(jax.random.PRNGKey(seed), cfg, run)
+    key = jax.random.PRNGKey(seed)
+    if dp_mesh is None:
+        state = init_train_state(key, cfg, run)
+    else:
+        # built replicated in place: a state made on one device and then
+        # broadcast would sit on that device twice during the first step
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        state = jax.jit(
+            lambda k: init_train_state(k, cfg, run),
+            out_shardings=NamedSharding(dp_mesh, P()))(key)
 
     persistable = lambda s: s
     restore_state = ckpt.restore
     save_meta: dict = {}
     if dp_mesh is not None:
-        # donation is incompatible with the replicated-in spec here:
-        # keep it simple, the DP step's state is small on debug meshes
-        train_step = jax.jit(make_dp_train_step(cfg, run, dp_mesh))
+        train_step = jax.jit(make_dp_train_step(cfg, run, dp_mesh),
+                             donate_argnums=(0,) if donate else ())
         ax = run.dp_axis_name
         members = ax if isinstance(ax, tuple) else (ax,)
         workers = 1
@@ -302,12 +311,13 @@ def run_training(cfg, run: RunConfig, loop: LoopConfig, *,
         if step % loop.log_every == 0:
             log.info("step %d loss %.4f grad_norm %.3f (%.3fs)",
                      step, metrics["loss"], metrics["grad_norm"], dt)
-        if (step + 1) % loop.ckpt_every == 0:
+        if loop.ckpt_every and (step + 1) % loop.ckpt_every == 0:
             ckpt.save_async(step + 1, persistable(state),
                             metadata=save_meta)
 
     ckpt.wait()
-    ckpt.save(loop.num_steps, persistable(state), metadata=save_meta)
+    if loop.ckpt_every:
+        ckpt.save(loop.num_steps, persistable(state), metadata=save_meta)
     if tlog is not None:
         tlog.close()
     return state, history
@@ -349,8 +359,9 @@ def run_training_sharded(cfg, run: RunConfig, loop: LoopConfig, mesh,
             if step % loop.log_every == 0:
                 log.info("step %d loss %.4f", step,
                          history[-1]["loss"])
-            if (step + 1) % loop.ckpt_every == 0:
+            if loop.ckpt_every and (step + 1) % loop.ckpt_every == 0:
                 ckpt.save_async(step + 1, state)
         ckpt.wait()
-        ckpt.save(loop.num_steps, state)
+        if loop.ckpt_every:
+            ckpt.save(loop.num_steps, state)
     return state, history

@@ -411,7 +411,7 @@ def make_dp_step(cfg: MLPConfig, scfg: SketchConfig, variant: str,
     agree to last-ulp compiler noise only — the freely-inlined MLP
     backward is re-fused by XLA per program, unlike the LM's
     scan/remat-bounded backward, which IS bitwise end to end."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.collectives import psum_flat_segments
@@ -479,7 +479,7 @@ def make_dp_step(cfg: MLPConfig, scfg: SketchConfig, variant: str,
         step, mesh=mesh,
         in_specs=(P(), P(), P(), P(axis), P(axis)),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False))
+        check_vma=False))
 
 
 @dataclasses.dataclass

@@ -709,7 +709,7 @@ def make_dp_train_step(cfg: ArchConfig, run: RunConfig, mesh):
     survives restarts)."""
     import dataclasses
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     run = finalize_run(cfg, run)
@@ -743,4 +743,4 @@ def make_dp_train_step(cfg: ArchConfig, run: RunConfig, mesh):
     step = make_train_step(cfg, run)
     return shard_map(step, mesh=mesh,
                      in_specs=(P(), P(ax)), out_specs=(P(), P()),
-                     check_rep=False)
+                     check_vma=False)

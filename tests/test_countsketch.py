@@ -465,7 +465,7 @@ def test_countsketch_psum_path_under_shard_map(rng):
     """The dp_axis_name path: a 1-device shard_map exercises the psum
     merge wiring (W=1 — psum identity) and must match the axis-free
     path exactly."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     cfg = CompressionConfig(mode="countsketch", cs_rows=3, cs_cols=256,
@@ -479,7 +479,7 @@ def test_countsketch_psum_path_under_shard_map(rng):
         lambda g, e: compress_grads_countsketch(
             g, e, cfg, axis_name="data")[:2],
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     got, got_err = fn(grads, err)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -506,7 +506,7 @@ def test_countsketch_psum_matches_single_worker_on_4_devices():
     env["PYTHONPATH"] = os.path.join(repo, "src")
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.optim.compression import CompressionConfig
         from repro.optim.sketched_sgd import (
@@ -524,7 +524,7 @@ def test_countsketch_psum_matches_single_worker_on_4_devices():
             lambda g, e: compress_grads_countsketch(
                 {"w": g.reshape(dim)}, e, cfg, axis_name="data")[0]["w"],
             mesh=mesh, in_specs=(P("data"), P()), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         got = fn(worker_g, err)
 
         want = compress_grads_countsketch(
@@ -560,7 +560,7 @@ def test_w4_shard_map_end_to_end_step_with_p2():
     env["PYTHONPATH"] = os.path.join(repo, "src")
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.flatten_util import ravel_pytree
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.configs import get_arch, reduced
@@ -594,7 +594,7 @@ def test_w4_shard_map_end_to_end_step_with_p2():
                     lambda x: x.reshape(1, -1), ne)
             fn = shard_map(
                 compress, mesh=mesh, in_specs=(P("data"), P()),
-                out_specs=(P(), P("data")), check_rep=False)
+                out_specs=(P(), P("data")), check_vma=False)
             comp, new_err = fn(worker_g, err)
             c = comp["w"]
             # per-worker exact mass conservation: v_new + update == v_pre

@@ -35,7 +35,7 @@ def test_sharded_step_matches_unsharded(arch):
     out = _run(f"""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_arch, reduced
-        from repro.launch.mesh import make_debug_mesh, rules_for_mesh
+        from repro.launch.mesh import make_mesh, rules_for_mesh
         from repro.parallel.sharding import use_rules, param_shardings
         from repro.train.state import RunConfig, init_train_state
         from repro.train.step import make_train_step
@@ -57,7 +57,7 @@ def test_sharded_step_matches_unsharded(arch):
         state0 = init_train_state(key, cfg, run)
         s_ref, m_ref = jax.jit(make_train_step(cfg, run))(state0, batch)
 
-        mesh = make_debug_mesh(2, 4)
+        mesh = make_mesh(2, 4)
         rules = rules_for_mesh(mesh)
         with use_rules(rules), mesh:
             state = init_train_state(key, cfg, run)
@@ -85,7 +85,7 @@ def test_dp_exact_sketch_matches_full_batch_w4():
     out = _run("""
         import dataclasses, functools
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.sketches import ema_triple_update
 
@@ -107,7 +107,7 @@ def test_dp_exact_sketch_matches_full_batch_w4():
         dp = jax.jit(shard_map(
             lambda sh: upd(x0, x0, x0, a=sh, axis_name="data"),
             mesh=mesh, in_specs=P("data"), out_specs=P(),
-            check_rep=False))
+            check_vma=False))
         got = dp(a)
 
         # single-worker full-batch reference: per-shard increments
@@ -184,7 +184,7 @@ def test_fsdp_strategy_matches_megatron():
     out = _run("""
         import jax
         from repro.configs import get_arch, reduced
-        from repro.launch.mesh import make_debug_mesh, rules_for_mesh
+        from repro.launch.mesh import make_mesh, rules_for_mesh
         from repro.parallel.sharding import use_rules, param_shardings
         from repro.train.state import RunConfig, init_train_state
         from repro.train.step import make_train_step
@@ -198,7 +198,7 @@ def test_fsdp_strategy_matches_megatron():
         tokens, labels = lm_batch(key, 4, 32, cfg.vocab_size)
         batch = {"tokens": tokens, "labels": labels}
         losses = []
-        mesh = make_debug_mesh(2, 4)
+        mesh = make_mesh(2, 4)
         for strat in ("megatron", "fsdp"):
             rules = rules_for_mesh(mesh, strategy=strat)
             with use_rules(rules), mesh:
@@ -221,7 +221,7 @@ def test_mini_multipod_dryrun_compiles():
     out = _run("""
         import jax, jax.numpy as jnp
         from repro.configs import get_arch, reduced
-        from repro.launch.mesh import make_debug_mesh, rules_for_mesh
+        from repro.launch.mesh import make_mesh, rules_for_mesh
         from repro.parallel.sharding import use_rules, param_shardings
         from repro.train.state import RunConfig, abstract_train_state
         from repro.train.step import make_train_step
@@ -231,7 +231,7 @@ def test_mini_multipod_dryrun_compiles():
         cfg = reduced(get_arch("gemma3-27b"))
         st = SketchSettings(enabled=True, k_max=9)
         run = RunConfig(seq_len=32, global_batch=8, sketch=st)
-        mesh = make_debug_mesh(2, 2, multi_pod=True)
+        mesh = make_mesh(2, 2, n_pod=2)
         rules = rules_for_mesh(mesh)
         with use_rules(rules), mesh:
             state = abstract_train_state(cfg, run)
@@ -269,7 +269,7 @@ def test_fused_flat_psum_bitwise_parity_mlp_variant_trees():
     out = _run("""
         import dataclasses, functools
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.configs.paper import MLPConfig
         from repro.core.sketch import SketchConfig
@@ -340,7 +340,7 @@ def test_fused_flat_psum_bitwise_parity_mlp_variant_trees():
                 sh = lambda f: jax.jit(shard_map(
                     lambda a: f(a.reshape(L, Tl, d)),
                     mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                    check_rep=False))
+                    check_vma=False))
                 got = sh(fused)(acts)
                 want = sh(per_leaf)(acts)
                 for g, w in zip(jax.tree.leaves(got),
@@ -387,7 +387,7 @@ def test_fused_flat_psum_bitwise_parity_mlp_variant_trees():
             sh = lambda f: jax.jit(shard_map(
                 lambda a: f(a.reshape(L, Tl, d)),
                 mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                check_rep=False))
+                check_vma=False))
             got = sh(fused)(acts)
             want = sh(per_node)(acts)
             for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -709,7 +709,7 @@ def test_overlap_partition_psum_bitwise_parity_mlp_variant_trees():
     out = _run("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.configs.paper import MLPConfig
         from repro.core.sketch import SketchConfig
@@ -816,7 +816,7 @@ def test_overlap_partition_psum_bitwise_parity_mlp_variant_trees():
                 sh = lambda f: jax.jit(shard_map(
                     lambda a: f(a.reshape(L, Tl, d)),
                     mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                    check_rep=False))
+                    check_vma=False))
                 got = sh(overlap)(acts)
                 want = sh(per_leaf)(acts)
                 for g, w in zip(jax.tree.leaves(got),
@@ -872,7 +872,7 @@ def test_overlap_partition_psum_bitwise_parity_mlp_variant_trees():
             sh = lambda f: jax.jit(shard_map(
                 lambda a: f(a.reshape(L, Tl, d)),
                 mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                check_rep=False))
+                check_vma=False))
             got = sh(overlap)(acts)
             want = sh(per_node)(acts)
             for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
@@ -991,7 +991,7 @@ def test_dp_differential_psparse_w4():
     out = _run("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.configs.paper import MLPConfig
         from repro.core.sketch import SketchConfig
@@ -1089,7 +1089,7 @@ def test_dp_differential_psparse_w4():
         sh = lambda f: jax.jit(shard_map(
             lambda a: f(a.reshape(L, Tl, d)),
             mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-            check_rep=False))
+            check_vma=False))
         want = sh(per_node)(acts)
         for name, f in (("fused", fused), ("overlap", overlap)):
             got = sh(f)(acts)
@@ -1200,7 +1200,8 @@ RS_LM_CODE = """
 
     STEPS = __STEPS__
     cfg = reduced(get_arch("tinyllama-1.1b"))      # sketch_mode=backprop
-    mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
     mesh4 = Mesh(np.array(jax.devices()[:4]), ("data",))
 
     def mk(dp_axis, collective, merge):
@@ -1327,11 +1328,12 @@ def test_tuple_axis_ema_psum_matches_1d_w8():
     out = _run("""
         import functools
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.sketches import ema_triple_update
 
-        mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
         mesh4 = Mesh(np.array(jax.devices()[:4]), ("data",))
         W, Tl, d, k = 4, 16, 24, 9
         ks = jax.random.split(jax.random.PRNGKey(0), 6)
@@ -1347,12 +1349,12 @@ def test_tuple_axis_ema_psum_matches_1d_w8():
         ref = jax.jit(shard_map(
             lambda sh: upd(x0, x0, x0, a=sh, axis_name="data"),
             mesh=mesh4, in_specs=P("data"), out_specs=P(),
-            check_rep=False))(a)
+            check_vma=False))(a)
         got = jax.jit(shard_map(
             lambda sh: upd(x0, x0, x0, a=sh,
                            axis_name=("pod", "data")),
             mesh=mesh8, in_specs=P(("pod", "data")), out_specs=P(),
-            check_rep=False))(a)
+            check_vma=False))(a)
         for g, r in zip(got, ref):
             assert np.array_equal(np.asarray(g), np.asarray(r)), \\
                 "tuple-axis psum is not bitwise the 1D psum"
@@ -1381,7 +1383,8 @@ def test_rs_loop_checkpoint_resume_preserves_worker_shards_w8():
         from repro.train.state import RunConfig
 
         cfg = reduced(get_arch("tinyllama-1.1b"))
-        mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh8 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
         def mk_run():
             return RunConfig(
@@ -1452,12 +1455,12 @@ def test_sketch_tp_specs_and_dryrun_report_w8():
         from repro.configs import SHAPES, get_arch
         from repro.launch.dryrun import (
             make_run_config, sketch_sharding_report)
-        from repro.launch.mesh import make_debug_mesh, rules_for_mesh
+        from repro.launch.mesh import make_mesh, rules_for_mesh
         from repro.parallel.sharding import (
             param_shardings, spec_for_sketch, use_rules)
         from repro.train.state import abstract_train_state
 
-        mesh = make_debug_mesh(2, 2, multi_pod=True)
+        mesh = make_mesh(2, 2, n_pod=2)
         rules = rules_for_mesh(mesh)
         f32 = jnp.float32
         trip = jax.ShapeDtypeStruct((4, 128, 9), f32)

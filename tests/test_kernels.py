@@ -75,3 +75,29 @@ def test_mlstm_chunk_sweep(rng, B, H, S, Dk, Dv, W):
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(C_k), np.asarray(C_r),
                                atol=1e-4, rtol=1e-4)
+
+
+def test_import_touches_no_backend_and_rule_reads_platform():
+    """Importing the library initialises no JAX backend (an entry point
+    must be able to place the compile cache or call
+    `jax.distributed.initialize` first); the one interpret rule reads
+    the platform only when asked, and interprets off the TPU."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "from jax._src import xla_bridge as xb\n"
+        "import repro.kernels, repro.train.loop, repro.serve\n"
+        "import repro.launch.mesh, repro.launch.compile_cache\n"
+        "assert not xb.backends_are_initialized()\n"
+        "from repro.kernels.ops import interpret_mode\n"
+        "assert interpret_mode() is True\n"
+        "print('OK')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(repo, "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout

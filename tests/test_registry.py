@@ -124,13 +124,14 @@ def test_launch_reaches_specs_only_via_node_specs_for():
 # expert-axis sharding rule (multi-dim node stacks)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices")
 def test_spec_for_sketch_shards_expert_axis():
-    from repro.launch.mesh import make_debug_mesh, rules_for_mesh
+    from repro.launch.mesh import make_mesh, rules_for_mesh
     from repro.parallel.sharding import spec_for_sketch
     from jax.sharding import PartitionSpec as P
 
-    rules = rules_for_mesh(make_debug_mesh(2, 4))
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 devices")
+    rules = rules_for_mesh(make_mesh(2, 4))
     x = jax.ShapeDtypeStruct((2, 4, 64, 9), jnp.float32)
     # (L, E, d, k): E shards over the TP ("model") axis like the expert
     # weights, d keeps the ZeRO dp dim, k replicated

@@ -45,7 +45,7 @@ def _run(code: str, devices: int) -> str:
 
 RING_CODE = """
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.kernels.ring_allreduce import (
         ring_allreduce, ring_allreduce_ref)
@@ -66,7 +66,7 @@ RING_CODE = """
                 return y[None], res[None]
             f = shard_map(body, mesh=mesh, in_specs=P("data", None),
                           out_specs=(P("data", None), P("data", None)),
-                          check_rep=False)
+                          check_vma=False)
             y, res = jax.jit(f)(xs)
             y, res = np.asarray(y), np.asarray(res)
             for w in range(1, W):
@@ -83,7 +83,7 @@ RING_CODE = """
                 ps = shard_map(lambda a: jax.lax.psum(a, "data"),
                                mesh=mesh, in_specs=P("data", None),
                                out_specs=P("data", None),
-                               check_rep=False)
+                               check_vma=False)
                 yp = np.asarray(jax.jit(ps)(xs))[0]
                 assert np.array_equal(yr, yp), \\
                     (N, "f32 ring is not bitwise psum")

@@ -534,7 +534,7 @@ def test_increment_apply_decomposition_matches_update(rng):
     actually replaces, is the reference.)"""
     import functools
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.sketches.update import (
@@ -559,7 +559,7 @@ def test_increment_apply_decomposition_matches_update(rng):
             use_kernel=use_kernel)
         want = jax.jit(shard_map(
             lambda aa: upd(x0, y0, z0, a=aa), mesh=mesh,
-            in_specs=P("data"), out_specs=P(), check_rep=False))(a)
+            in_specs=P("data"), out_specs=P(), check_vma=False))(a)
         incs = ema_triple_increment(x0, y0, z0, a, ups, omg, phi, psi,
                                     beta, ka, use_kernel=use_kernel)
         got = [ema_apply_increment(s, i, beta, ka)

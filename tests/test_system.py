@@ -147,3 +147,26 @@ def test_serve_engine_greedy_matches_forward(rng):
     want0 = jnp.argmax(ref[:, -1], axis=-1)
     np.testing.assert_array_equal(np.asarray(out[:, 0]),
                                   np.asarray(want0))
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """Entry points keep JAX's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says and set no other directory; without
+    it the cache goes to one fixed, git-ignored path in the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    repo = Path(__file__).resolve().parents[1]
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = compile_cache.enable_compile_cache()
+        assert fixed == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
