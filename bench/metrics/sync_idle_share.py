@@ -1,0 +1,8 @@
+"""sync_idle_share: the share of the traced window in which the device
+was idle while the loop's `loop/sync` span (the host's reads of the
+step's metrics) was open (`bench/scopes.py`)."""
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.idle_share_under(ctx, scopes.SYNC_SPAN)

@@ -24,7 +24,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import logging
 import math
 import os
 import sys
@@ -46,6 +45,7 @@ from repro.models.transformer import (  # noqa: E402
 )
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.sketches import ema_triple_update  # noqa: E402
+from repro.telemetry import compile_counter  # noqa: E402
 from repro.train.loop import LoopConfig, run_training  # noqa: E402
 from repro.train.state import RunConfig  # noqa: E402
 
@@ -118,55 +118,17 @@ def run_config(sketch: bool, *, batch: int = BATCH,
         dp_axis_name=dp_axis, dp_workers=dp_workers)
 
 
-class CompileCounter:
-    """Counts programs lowered for compilation (one per jit cache miss,
-    whether or not the persistent cache then serves the executable) and
-    sums backend compile seconds."""
-
-    def __init__(self):
-        self.lowerings = 0
-        self.compile_s = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self)
-
-    def __call__(self, name, secs, **_):
-        if name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
-            self.lowerings += 1
-        elif name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-
-class StepMarks(logging.Handler):
-    """Records the compile count when the loop logs each step."""
-
-    def __init__(self, counter: CompileCounter):
-        super().__init__(logging.INFO)
-        self.counter = counter
-        self.lowerings_at: dict[int, int] = {}
-
-    def emit(self, record):
-        if record.msg.startswith("step "):
-            self.lowerings_at[record.args[0]] = self.counter.lowerings
-
-
-def train(cfg, run, steps: int, counter: CompileCounter, *, seed: int,
-          dp_mesh=None):
+def train(cfg, run, steps: int, *, seed: int, dp_mesh=None):
     """`run_training` in a fresh checkpoint directory outside the
-    checkout, writing no checkpoint. Returns (state, history, lowerings
-    counted after step 0)."""
-    train_log = logging.getLogger("repro.train")
-    train_log.setLevel(logging.INFO)
-    marks = StepMarks(counter)
-    train_log.addHandler(marks)
-    try:
-        with tempfile.TemporaryDirectory(prefix="chip_smoke_ckpt_") as d:
-            loop = LoopConfig(num_steps=steps, ckpt_every=0, ckpt_dir=d,
-                              log_every=1)
-            state, hist = run_training(cfg, run, loop, seed=seed,
-                                       dp_mesh=dp_mesh)
-    finally:
-        train_log.removeHandler(marks)
+    checkout, writing no checkpoint. Returns (state, history, programs
+    lowered after step 0, from the loop's per-step `compiles`)."""
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_ckpt_") as d:
+        loop = LoopConfig(num_steps=steps, ckpt_every=0, ckpt_dir=d,
+                          log_every=1)
+        state, hist = run_training(cfg, run, loop, seed=seed,
+                                   dp_mesh=dp_mesh)
     check(len(hist) == steps, f"ran {len(hist)} of {steps} steps")
-    return state, hist, counter.lowerings - marks.lowerings_at[0]
+    return state, hist, sum(h["compiles"] for h in hist[1:])
 
 
 def check_history(hist, tag: str) -> None:
@@ -207,7 +169,7 @@ def rel_gap(a, b) -> float:
 def phase_train(cfg, counter, seed):
     run = run_config(True)
     t0 = time.perf_counter()
-    state, hist, late = train(cfg, run, STEPS, counter, seed=seed)
+    state, hist, late = train(cfg, run, STEPS, seed=seed)
     wall = time.perf_counter() - t0
     say("train", arch=ARCH, layers=cfg.num_layers, d_model=cfg.d_model,
         heads=f"{cfg.num_heads}/{cfg.num_kv_heads}x"
@@ -246,8 +208,8 @@ def phase_train(cfg, counter, seed):
     return loss0, probe
 
 
-def phase_sketch_off(cfg, counter, seed, loss_on):
-    _, hist, _ = train(cfg, run_config(False), 1, counter, seed=seed)
+def phase_sketch_off(cfg, seed, loss_on):
+    _, hist, _ = train(cfg, run_config(False), 1, seed=seed)
     check_history(hist, "sketch-off")
     loss_off = hist[0]["loss"]
     gap = abs(loss_on - loss_off)
@@ -372,7 +334,7 @@ def device_bytes(tree) -> list[int]:
     return [per[i] for i in sorted(per)]
 
 
-def phase_dp(cfg, devs, counter, seed):
+def phase_dp(cfg, devs, seed):
     """`launch/train.py --dp 4`: one step of the shard_map data-parallel
     step (fused layout, sketch on) against the four batch shards
     accumulated in sequence on one chip (DESIGN.md §4)."""
@@ -385,7 +347,7 @@ def phase_dp(cfg, devs, counter, seed):
     W = len(devs)
     run = run_config(True, batch=W, dp_axis="data", dp_workers=W)
     mesh = Mesh(np.array(devs), ("data",))
-    state, hist, _ = train(cfg, run, 1, counter, seed=seed,
+    state, hist, _ = train(cfg, run, 1, seed=seed,
                            dp_mesh=mesh)
     check_history(hist, "dp")
     held = device_bytes(state)
@@ -433,7 +395,7 @@ def phase_dp(cfg, devs, counter, seed):
           f"DP state is not replicated evenly: {held}")
 
 
-def phase_tp(cfg, devs, counter, seed):
+def phase_tp(cfg, devs, seed):
     """The tensor-parallel mesh step (`run_training_sharded`) on a
     (data 1, model W) mesh against the unsharded one-chip step."""
     from repro.launch.mesh import make_mesh, rules_for_mesh
@@ -441,7 +403,7 @@ def phase_tp(cfg, devs, counter, seed):
 
     run = run_config(True)
     steps = 2
-    ref_state, ref, _ = train(cfg, run, steps, counter, seed=seed)
+    ref_state, ref, _ = train(cfg, run, steps, seed=seed)
     del ref_state
     check_history(ref, "unsharded")
     mesh = make_mesh(1, len(devs))
@@ -489,14 +451,14 @@ def main(argv=None) -> int:
     say("device", platform=devs[0].platform, kind=devs[0].device_kind,
         count=n, jax=jax.__version__,
         compile_cache=enable_compile_cache())
-    counter = CompileCounter()
+    counter = compile_counter()
     cfg = cut_config()
     if args.four_chips:
-        phase_dp(cfg, devs, counter, args.seed)
-        phase_tp(cfg, devs, counter, args.seed)
+        phase_dp(cfg, devs, args.seed)
+        phase_tp(cfg, devs, args.seed)
     else:
         loss0, probe = phase_train(cfg, counter, args.seed)
-        phase_sketch_off(cfg, counter, args.seed, loss0)
+        phase_sketch_off(cfg, args.seed, loss0)
         phase_kernel(probe, args.seed)
         phase_serve(cfg, args.seed)
     print(json.dumps({"ok": True, "device": {

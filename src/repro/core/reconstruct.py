@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.sketches.update import mask_columns
+from repro.telemetry import scope
 
 Array = jax.Array
 
@@ -77,22 +78,23 @@ def reconstruct(
     ridge: float = 1e-4,
 ) -> Reconstruction:
     """Reconstruct the node's batch activation matrix from its EMA triple."""
-    dt = jnp.promote_types(x_s.dtype, jnp.float32)
-    x_s = mask_columns(x_s.astype(dt), k_active)
-    y_s = mask_columns(y_s.astype(dt), k_active)
-    z_s = mask_columns(z_s.astype(dt), k_active)
-    omega = mask_columns(omega.astype(dt), k_active)
+    with scope("reconstruct"):
+        dt = jnp.promote_types(x_s.dtype, jnp.float32)
+        x_s = mask_columns(x_s.astype(dt), k_active)
+        y_s = mask_columns(y_s.astype(dt), k_active)
+        z_s = mask_columns(z_s.astype(dt), k_active)
+        omega = mask_columns(omega.astype(dt), k_active)
 
-    q_y = masked_qr(y_s, k_active)               # (d, k)
-    c_inter = q_y.T @ z_s                        # (k, s)
-    p_x = masked_qr(x_s.T, k_active)             # (k, k)
-    c = p_x.T @ c_inter.T                        # (k, k)  [s = k]
-    q_x = masked_qr(x_s, k_active)               # (d, k)
+        q_y = masked_qr(y_s, k_active)               # (d, k)
+        c_inter = q_y.T @ z_s                        # (k, s)
+        p_x = masked_qr(x_s.T, k_active)             # (k, k)
+        c = p_x.T @ c_inter.T                        # (k, k)  [s = k]
+        q_x = masked_qr(x_s, k_active)               # (d, k)
 
-    # left = Omega @ (Y^+ Q_Y) @ C   — all k-sized
-    ypq = _pinv_apply(y_s, q_y, k_active, mode, ridge)   # (k, k)
-    left = omega @ (ypq @ c)                     # (N_b, k)
-    return Reconstruction(left=left, right=q_x)
+        # left = Omega @ (Y^+ Q_Y) @ C   — all k-sized
+        ypq = _pinv_apply(y_s, q_y, k_active, mode, ridge)   # (k, k)
+        left = omega @ (ypq @ c)                     # (N_b, k)
+        return Reconstruction(left=left, right=q_x)
 
 
 def reconstruct_dense_faithful(x_s, y_s, z_s, omega, k_active,

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.models.layers import dense_init, rope
 from repro.parallel.sharding import constrain
+from repro.telemetry import scope
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -63,54 +64,61 @@ def chunked_causal_attention(
     window: int | None,
     chunk: int = 1024,
 ) -> Array:
-    B, S, KV, G, D = q.shape
-    chunk = min(chunk, S)
-    assert S % chunk == 0, (S, chunk)
-    n_chunks = S // chunk
-    scale = D ** -0.5
-    qf = (q * scale).astype(q.dtype)
-    q_pos = jnp.arange(S)
+    with scope("attention"):
+        B, S, KV, G, D = q.shape
+        chunk = min(chunk, S)
+        assert S % chunk == 0, (S, chunk)
+        n_chunks = S // chunk
+        scale = D ** -0.5
+        qf = (q * scale).astype(q.dtype)
+        q_pos = jnp.arange(S)
 
-    m0 = jnp.full((B, KV, G, S), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, KV, G, S), jnp.float32)
-    a0 = jnp.zeros((B, KV, G, S, D), jnp.float32)
+        m0 = jnp.full((B, KV, G, S), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((B, KV, G, S), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, S, D), jnp.float32)
 
-    def body(carry, j):
-        m, l, acc = carry
-        kj = jax.lax.dynamic_slice_in_dim(k, j * chunk, chunk, axis=1)
-        vj = jax.lax.dynamic_slice_in_dim(v, j * chunk, chunk, axis=1)
-        s = jnp.einsum(
-            "bqhgd,bkhd->bhgqk", qf, kj, preferred_element_type=jnp.float32
+        def body(carry, j):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * chunk, chunk,
+                                              axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * chunk, chunk,
+                                              axis=1)
+            s = jnp.einsum(
+                "bqhgd,bkhd->bhgqk", qf, kj,
+                preferred_element_type=jnp.float32
+            )
+            # additive 2D mask (broadcast at the add): a boolean 5D mask
+            # gets hoisted/stacked by XLA's loop optimizer into a
+            # (n_chunks, B, ...) pred carry — hundreds of MB per layer.
+            # Keep it (S, chunk) f32.
+            k_pos = j * chunk + jnp.arange(chunk)
+            bias = jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0,
+                             NEG_INF)
+            if window is not None:
+                bias = bias + jnp.where(
+                    (q_pos[:, None] - k_pos[None, :]) < window, 0.0, NEG_INF)
+            s = s + bias[None, None, None]
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l_new = l * corr + p.sum(axis=-1)
+            acc_new = acc * corr[..., None] + jnp.einsum(
+                "bhgqk,bkhd->bhgqd", p.astype(v.dtype), vj,
+                preferred_element_type=jnp.float32,
+            )
+            return (m_new, l_new, acc_new), None
+
+        # checkpoint the chunk body: the scan's VJP otherwise saves the
+        # (B,KV,G,S,chunk) softmax intermediates for every chunk —
+        # recomputing them in the backward sweep is the flash-attention
+        # trade.
+        body = jax.checkpoint(body, prevent_cse=False)
+        (m, l, acc), _ = jax.lax.scan(
+            body, (m0, l0, a0), jnp.arange(n_chunks)
         )
-        # additive 2D mask (broadcast at the add): a boolean 5D mask gets
-        # hoisted/stacked by XLA's loop optimizer into a (n_chunks, B, ...)
-        # pred carry — hundreds of MB per layer. Keep it (S, chunk) f32.
-        k_pos = j * chunk + jnp.arange(chunk)
-        bias = jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0, NEG_INF)
-        if window is not None:
-            bias = bias + jnp.where(
-                (q_pos[:, None] - k_pos[None, :]) < window, 0.0, NEG_INF)
-        s = s + bias[None, None, None]
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l_new = l * corr + p.sum(axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhgqk,bkhd->bhgqd", p.astype(v.dtype), vj,
-            preferred_element_type=jnp.float32,
-        )
-        return (m_new, l_new, acc_new), None
-
-    # checkpoint the chunk body: the scan's VJP otherwise saves the
-    # (B,KV,G,S,chunk) softmax intermediates for every chunk — recomputing
-    # them in the backward sweep is the flash-attention trade.
-    body = jax.checkpoint(body, prevent_cse=False)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0), jnp.arange(n_chunks)
-    )
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    # (B,KV,G,S,D) -> (B,S,KV,G,D)
-    return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        # (B,KV,G,S,D) -> (B,S,KV,G,D)
+        return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry import scope
+
 
 @dataclasses.dataclass(frozen=True)
 class AdamWConfig:
@@ -42,37 +44,38 @@ def clip_by_global_norm(grads, max_norm: float):
 
 def adamw_update(params, grads, state, cfg: AdamWConfig, lr_scale=1.0):
     """Returns (new_params, new_state, metrics)."""
-    if cfg.grad_clip > 0:
-        grads, gn = clip_by_global_norm(grads, cfg.grad_clip)
-    else:
-        gn = global_norm(grads)
-    count = state["count"] + 1
-    b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
-    lr = cfg.lr * lr_scale
+    with scope("adamw"):
+        if cfg.grad_clip > 0:
+            grads, gn = clip_by_global_norm(grads, cfg.grad_clip)
+        else:
+            gn = global_norm(grads)
+        count = state["count"] + 1
+        b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
+        lr = cfg.lr * lr_scale
 
-    def upd(p, g, m, v):
-        gf = g.astype(cfg.moment_dtype)
-        m_new = cfg.b1 * m + (1 - cfg.b1) * gf
-        v_new = cfg.b2 * v + (1 - cfg.b2) * gf * gf
-        step = (m_new / b1c) / (jnp.sqrt(v_new / b2c) + cfg.eps)
-        p_new = p.astype(jnp.float32) - lr * (
-            step + cfg.weight_decay * p.astype(jnp.float32))
-        return p_new.astype(p.dtype), m_new, v_new
+        def upd(p, g, m, v):
+            gf = g.astype(cfg.moment_dtype)
+            m_new = cfg.b1 * m + (1 - cfg.b1) * gf
+            v_new = cfg.b2 * v + (1 - cfg.b2) * gf * gf
+            step = (m_new / b1c) / (jnp.sqrt(v_new / b2c) + cfg.eps)
+            p_new = p.astype(jnp.float32) - lr * (
+                step + cfg.weight_decay * p.astype(jnp.float32))
+            return p_new.astype(p.dtype), m_new, v_new
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_m = treedef.flatten_up_to(state["m"])
-    flat_v = treedef.flatten_up_to(state["v"])
-    out = [upd(p, g, m, v) for p, g, m, v
-           in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_params = treedef.unflatten([o[0] for o in out])
-    new_state = {
-        "m": treedef.unflatten([o[1] for o in out]),
-        "v": treedef.unflatten([o[2] for o in out]),
-        "count": count,
-    }
-    return new_params, new_state, {"grad_norm": gn}
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_g = treedef.flatten_up_to(grads)
+        flat_m = treedef.flatten_up_to(state["m"])
+        flat_v = treedef.flatten_up_to(state["v"])
+        out = [upd(p, g, m, v) for p, g, m, v
+               in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_params = treedef.unflatten([o[0] for o in out])
+        new_state = {
+            "m": treedef.unflatten([o[1] for o in out]),
+            "v": treedef.unflatten([o[2] for o in out]),
+            "count": count,
+        }
+        return new_params, new_state, {"grad_norm": gn}
 
 
 def adamw_sparse_update(params, state, cfg: AdamWConfig, lr_scale=1.0,
@@ -100,41 +103,42 @@ def adamw_sparse_update(params, state, cfg: AdamWConfig, lr_scale=1.0,
     coordinate set (distinct), `unravel` the flat->pytree map used by
     the serial path — needed so `global_norm` reduces leaf-by-leaf in
     the serial order. Returns (new_params, new_state, metrics)."""
-    from jax.flatten_util import ravel_pytree
+    with scope("adamw"):
+        from jax.flatten_util import ravel_pytree
 
-    zeros = jax.tree.map(jnp.zeros_like, params)
-    p0, s0, _ = adamw_update(params, zeros, state, cfg, lr_scale)
+        zeros = jax.tree.map(jnp.zeros_like, params)
+        p0, s0, _ = adamw_update(params, zeros, state, cfg, lr_scale)
 
-    gtree = unravel(update)
-    gn = global_norm(gtree)
-    if cfg.grad_clip > 0:
-        scale = jnp.minimum(1.0,
-                            cfg.grad_clip / jnp.maximum(gn, 1e-12))
-    else:
-        scale = jnp.float32(1.0)
+        gtree = unravel(update)
+        gn = global_norm(gtree)
+        if cfg.grad_clip > 0:
+            scale = jnp.minimum(1.0,
+                                cfg.grad_clip / jnp.maximum(gn, 1e-12))
+        else:
+            scale = jnp.float32(1.0)
 
-    count = state["count"] + 1
-    b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
-    b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
-    lr = cfg.lr * lr_scale
+        count = state["count"] + 1
+        b1c = 1.0 - cfg.b1 ** count.astype(jnp.float32)
+        b2c = 1.0 - cfg.b2 ** count.astype(jnp.float32)
+        lr = cfg.lr * lr_scale
 
-    pf, unrav_p = ravel_pytree(params)
-    mf, _ = ravel_pytree(state["m"])
-    vf, _ = ravel_pytree(state["v"])
-    gf = (update[idx] * scale).astype(cfg.moment_dtype)
-    m_new = cfg.b1 * mf[idx] + (1 - cfg.b1) * gf
-    v_new = cfg.b2 * vf[idx] + (1 - cfg.b2) * gf * gf
-    step = (m_new / b1c) / (jnp.sqrt(v_new / b2c) + cfg.eps)
-    p_new = pf[idx] - lr * (step + cfg.weight_decay * pf[idx])
+        pf, unrav_p = ravel_pytree(params)
+        mf, _ = ravel_pytree(state["m"])
+        vf, _ = ravel_pytree(state["v"])
+        gf = (update[idx] * scale).astype(cfg.moment_dtype)
+        m_new = cfg.b1 * mf[idx] + (1 - cfg.b1) * gf
+        v_new = cfg.b2 * vf[idx] + (1 - cfg.b2) * gf * gf
+        step = (m_new / b1c) / (jnp.sqrt(v_new / b2c) + cfg.eps)
+        p_new = pf[idx] - lr * (step + cfg.weight_decay * pf[idx])
 
-    p0f, _ = ravel_pytree(p0)
-    m0f, _ = ravel_pytree(s0["m"])
-    v0f, _ = ravel_pytree(s0["v"])
-    new_params = unrav_p(p0f.at[idx].set(p_new))
-    new_state = {"m": unrav_p(m0f.at[idx].set(m_new)),
-                 "v": unrav_p(v0f.at[idx].set(v_new)),
-                 "count": s0["count"]}
-    return new_params, new_state, {"grad_norm": gn}
+        p0f, _ = ravel_pytree(p0)
+        m0f, _ = ravel_pytree(s0["m"])
+        v0f, _ = ravel_pytree(s0["v"])
+        new_params = unrav_p(p0f.at[idx].set(p_new))
+        new_state = {"m": unrav_p(m0f.at[idx].set(m_new)),
+                     "v": unrav_p(v0f.at[idx].set(v_new)),
+                     "count": s0["count"]}
+        return new_params, new_state, {"grad_norm": gn}
 
 
 # --- plain SGD (paper §5.3 problematic config uses SGD) -------------------

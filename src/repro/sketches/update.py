@@ -27,6 +27,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry import scope
+
 Array = jax.Array
 
 
@@ -109,44 +111,47 @@ def ema_triple_update(
     use_kernel: bool | None = None,  # None -> kernels.ops.pallas_enabled()
 ) -> tuple[Array, Array, Array]:
     """One EMA sketch update; returns masked (x, y, z) in x_s.dtype."""
-    a = jax.lax.stop_gradient(a)
-    dt = x_s.dtype
-    ups = mask_columns(upsilon.astype(dt), k_active)
-    omg = mask_columns(omega.astype(dt), k_active)
-    ph = mask_columns(phi.astype(dt), k_active)
-    ps = mask_columns(psi.astype(dt), k_active)
+    with scope("sketch_update"):
+        a = jax.lax.stop_gradient(a)
+        dt = x_s.dtype
+        ups = mask_columns(upsilon.astype(dt), k_active)
+        omg = mask_columns(omega.astype(dt), k_active)
+        ph = mask_columns(phi.astype(dt), k_active)
+        ps = mask_columns(psi.astype(dt), k_active)
 
-    if use_kernel is None:
-        from repro.kernels.ops import pallas_enabled
-        use_kernel = pallas_enabled()
+        if use_kernel is None:
+            from repro.kernels.ops import pallas_enabled
+            use_kernel = pallas_enabled()
 
-    if use_kernel and a_out is None:
-        return _fused_kernel_update(
-            x_s, y_s, z_s, a, ups, omg, ph, ps, beta, k_active, axis_name)
+        if use_kernel and a_out is None:
+            return _fused_kernel_update(
+                x_s, y_s, z_s, a, ups, omg, ph, ps, beta, k_active,
+                axis_name)
 
-    at = a.astype(dt).T                                    # (d, T)
-    aot = at if a_out is None \
-        else jax.lax.stop_gradient(a_out).astype(dt).T
-    if axis_name is None:
-        x_new = beta * x_s + (1.0 - beta) * (at @ ups)
-        y_new = beta * y_s + (1.0 - beta) * (aot @ omg)
-        z_new = beta * z_s + (1.0 - beta) * ((aot @ ph) * ps[None, :])
-    else:
-        # full-batch increments: sum the per-shard contractions first
-        inc_x = jax.lax.psum((1.0 - beta) * (at @ ups), axis_name)
-        inc_y = jax.lax.psum((1.0 - beta) * (aot @ omg), axis_name)
-        inc_z = jax.lax.psum(
-            (1.0 - beta) * ((aot @ ph) * ps[None, :]), axis_name)
-        x_new = beta * x_s + inc_x
-        y_new = beta * y_s + inc_y
-        z_new = beta * z_s + inc_z
-    # keep masked columns exactly zero (EMA of zero is zero, but guard
-    # against drift after a rank decrease)
-    return (
-        mask_columns(x_new, k_active),
-        mask_columns(y_new, k_active),
-        mask_columns(z_new, k_active),
-    )
+        at = a.astype(dt).T                                    # (d, T)
+        aot = at if a_out is None \
+            else jax.lax.stop_gradient(a_out).astype(dt).T
+        if axis_name is None:
+            x_new = beta * x_s + (1.0 - beta) * (at @ ups)
+            y_new = beta * y_s + (1.0 - beta) * (aot @ omg)
+            z_new = beta * z_s + (1.0 - beta) * (
+                (aot @ ph) * ps[None, :])
+        else:
+            # full-batch increments: sum the per-shard contractions first
+            inc_x = jax.lax.psum((1.0 - beta) * (at @ ups), axis_name)
+            inc_y = jax.lax.psum((1.0 - beta) * (aot @ omg), axis_name)
+            inc_z = jax.lax.psum(
+                (1.0 - beta) * ((aot @ ph) * ps[None, :]), axis_name)
+            x_new = beta * x_s + inc_x
+            y_new = beta * y_s + inc_y
+            z_new = beta * z_s + inc_z
+        # keep masked columns exactly zero (EMA of zero is zero, but
+        # guard against drift after a rank decrease)
+        return (
+            mask_columns(x_new, k_active),
+            mask_columns(y_new, k_active),
+            mask_columns(z_new, k_active),
+        )
 
 
 def _fused_kernel_update(x_s, y_s, z_s, a, ups, omg, ph, ps, beta,
@@ -249,8 +254,9 @@ def ema_apply_increment(x_s: Array, inc: Array, beta: float,
     ``mask(beta * x + inc)`` in the increment's dtype, cast back to the
     sketch dtype — the exact accumulate formula of both the jnp and the
     kernel ``axis_name`` branches above."""
-    xn = beta * x_s.astype(inc.dtype) + inc
-    return mask_columns(xn.astype(x_s.dtype), k_active)
+    with scope("sketch_update"):
+        xn = beta * x_s.astype(inc.dtype) + inc
+        return mask_columns(xn.astype(x_s.dtype), k_active)
 
 
 # ---------------------------------------------------------------------------

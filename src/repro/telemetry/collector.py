@@ -15,13 +15,17 @@ from __future__ import annotations
 
 import contextlib
 import time
+from typing import TYPE_CHECKING
 
 import jax
 import numpy as np
 
-from repro.core.monitor import (
-    METRIC_NAMES, MonitorState, PathologyThresholds, detect_pathologies,
-)
+if TYPE_CHECKING:
+    from repro.core.monitor import MonitorState, PathologyThresholds
+
+# `repro.core.monitor` is imported where it is used: the model modules
+# import this package for their scope names (`instrument.scope`), and
+# `repro.core` imports them.
 
 
 def latest_reading(state: MonitorState) -> np.ndarray | None:
@@ -41,6 +45,8 @@ def node_metrics(reading: np.ndarray | None,
     the schema's ``nodes`` field. Empty for a warming-up ring."""
     if reading is None:
         return {}
+    from repro.core.monitor import METRIC_NAMES
+
     if reading.shape[0] != len(paths):
         raise ValueError(
             f"reading has {reading.shape[0]} rows but {len(paths)} "
@@ -64,20 +70,26 @@ def flag_paths(flags: dict, paths: list[str]) -> dict:
 
 
 def monitor_report(state: MonitorState, paths: list[str], k_active: int,
-                   th: PathologyThresholds = PathologyThresholds(),
+                   th: PathologyThresholds | None = None,
                    ) -> tuple[dict, dict]:
     """One-stop drain: (nodes, flags) for a TelemetryRecord from the
-    device ring buffer. Safe on an empty ring (both empty)."""
+    device ring buffer (default thresholds unless `th` is given). Safe
+    on an empty ring (both empty)."""
+    from repro.core.monitor import PathologyThresholds, detect_pathologies
+
     reading = latest_reading(state)
     if reading is None:
         return {}, {}
+    th = PathologyThresholds() if th is None else th
     flags = jax.device_get(detect_pathologies(state, k_active, th))
     return node_metrics(reading, paths), flag_paths(flags, paths)
 
 
 @contextlib.contextmanager
 def span(spans: dict, name: str):
-    """Scoped wall-clock timer accumulating into ``spans[name]``.
+    """Scoped wall-clock timer accumulating into ``spans[name]``, inside
+    a ``jax.profiler.TraceAnnotation(name)``: a profiled run shows the
+    span on the host's timeline, on the clock of the device's ops.
 
         with span(spans, "decode") as block:
             out = step(...)
@@ -92,10 +104,11 @@ def span(spans: dict, name: str):
         pending.append(x)
         return x
 
-    t0 = time.perf_counter()
-    try:
-        yield block
-    finally:
-        for x in pending:
-            jax.block_until_ready(x)
-        spans[name] = spans.get(name, 0.0) + time.perf_counter() - t0
+    with jax.profiler.TraceAnnotation(name):
+        t0 = time.perf_counter()
+        try:
+            yield block
+        finally:
+            for x in pending:
+                jax.block_until_ready(x)
+            spans[name] = spans.get(name, 0.0) + time.perf_counter() - t0

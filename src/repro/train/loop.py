@@ -10,14 +10,18 @@
   * NaN guard — the step itself skips non-finite updates; `max_skips`
     consecutive skips triggers rewind to the last checkpoint;
   * adaptive rank — per-epoch controller call (paper Algorithm 1) with
-    projection refresh via fold_in on rank change.
+    projection refresh via fold_in on rank change;
+  * instrumentation — each step is one `StepTraceAnnotation` split into
+    the host spans `telemetry.LOOP_SPANS` (feed, dispatch, sync,
+    control, record, ckpt), each also a profiler `TraceAnnotation`;
+    every history entry and telemetry record carries their seconds and
+    `compiles`, the programs the step lowered (DESIGN.md §11).
 """
 from __future__ import annotations
 
 import dataclasses
 import logging
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +33,10 @@ from repro.core.adaptive import adaptive_step
 from repro.data.pipeline import PipelineConfig, host_batch
 from repro.sketches import node_paths, refresh_tree
 from repro.sketches.shard import refresh_sharded_tree
-from repro.telemetry import TelemetryLog, TelemetryRecord, monitor_report
+from repro.telemetry import (
+    STEP_ANNOTATION, TelemetryLog, TelemetryRecord, compile_counter,
+    monitor_report, span,
+)
 from repro.train.state import RunConfig, TrainState, init_train_state
 from repro.train.step import (
     collective_plan, make_dp_train_step, make_train_step,
@@ -52,6 +59,18 @@ def _refresh_sketch(sketch):
     if hasattr(sketch, "nodes"):
         return refresh_sketch_tree(sketch)
     return refresh_sharded_sketch_tree(sketch)
+
+
+def _close_entry(entry: dict, spans: dict, compiles: int, *,
+                 first: bool) -> None:
+    """Completes a step's history entry with its host spans and the
+    programs it lowered; a step after the first that lowered one is
+    named in a warning."""
+    entry["compiles"] = compiles
+    entry["spans"] = spans
+    if compiles and not first:
+        log.warning("recompiled: %d program(s) lowered in step %d",
+                    compiles, entry["step"])
 
 
 @dataclasses.dataclass
@@ -239,86 +258,119 @@ def run_training(cfg, run: RunConfig, loop: LoopConfig, *,
     sk_paths = node_paths(state.sketch) \
         if state.sketch is not None else []
 
+    # each step runs as the host spans LOOP_SPANS (DESIGN.md §11), on
+    # the profiler's timeline when it traces; a step's telemetry record
+    # is written in the next step's loop/record, once its spans are in
+    counter = compile_counter()
+    done = None
     for step in range(step0, loop.num_steps):
-        tokens, labels = host_batch(pipe, step)
-        t0 = time.perf_counter()
-        state, metrics = train_step(state, {"tokens": tokens,
-                                            "labels": labels})
-        metrics = {k: float(v) for k, v in metrics.items()}
-        dt = time.perf_counter() - t0
+        spans: dict = {}
+        lowered = counter.lowerings
+        with jax.profiler.StepTraceAnnotation(STEP_ANNOTATION,
+                                              step_num=step):
+            with span(spans, "loop/feed"):
+                tokens, labels = host_batch(pipe, step)
+            with span(spans, "loop/dispatch"):
+                state, metrics = train_step(state, {"tokens": tokens,
+                                                    "labels": labels})
+            with span(spans, "loop/sync"):
+                metrics = {k: float(v) for k, v in metrics.items()}
+            dt = spans["loop/dispatch"] + spans["loop/sync"]
 
-        # straggler watchdog
-        if ema_t is None:
-            ema_t = dt
-        if dt > loop.straggler_factor * ema_t:
-            stragglers += 1
-            log.warning("straggler step %d: %.3fs vs EMA %.3fs",
-                        step, dt, ema_t)
-            if stragglers >= loop.straggler_budget:
-                log.error("straggler budget exhausted; checkpoint+abort")
-                ckpt.save(step + 1, persistable(state),
-                          metadata=save_meta)
-                sys.exit(75)
-        else:
-            stragglers = 0
-        ema_t = 0.9 * ema_t + 0.1 * dt
+            with span(spans, "loop/control"):
+                # straggler watchdog
+                if ema_t is None:
+                    ema_t = dt
+                if dt > loop.straggler_factor * ema_t:
+                    stragglers += 1
+                    log.warning("straggler step %d: %.3fs vs EMA %.3fs",
+                                step, dt, ema_t)
+                    if stragglers >= loop.straggler_budget:
+                        log.error("straggler budget exhausted; "
+                                  "checkpoint+abort")
+                        ckpt.save(step + 1, persistable(state),
+                                  metadata=save_meta)
+                        sys.exit(75)
+                else:
+                    stragglers = 0
+                ema_t = 0.9 * ema_t + 0.1 * dt
 
-        # NaN-guard rewind
-        new_skip_total = int(metrics["skipped_total"])
-        consec_skips = consec_skips + 1 \
-            if new_skip_total > last_skip_total else 0
-        last_skip_total = new_skip_total
-        if consec_skips >= loop.max_skips and ckpt.latest_step() is not None:
-            log.error("%d consecutive skipped steps; rewinding", consec_skips)
-            state, _ = restore_state(state)
-            consec_skips = 0
-            continue
+                # NaN-guard rewind
+                new_skip_total = int(metrics["skipped_total"])
+                consec_skips = consec_skips + 1 \
+                    if new_skip_total > last_skip_total else 0
+                last_skip_total = new_skip_total
+                if consec_skips >= loop.max_skips and \
+                        ckpt.latest_step() is not None:
+                    log.error("%d consecutive skipped steps; rewinding",
+                              consec_skips)
+                    state, _ = restore_state(state)
+                    consec_skips = 0
+                    continue
 
-        # adaptive rank controller (per pseudo-epoch)
-        if (loop.steps_per_epoch and run.adaptive is not None
-                and state.sketch is not None
-                and (step + 1) % loop.steps_per_epoch == 0):
-            adaptive, new_rank, changed = adaptive_step(
-                state.adaptive, state.sketch.rank,
-                jnp.asarray(metrics["loss"], jnp.float32), run.adaptive)
-            sketch = dataclasses.replace(state.sketch, rank=new_rank)
-            if bool(changed):
-                # paper Alg. 1 "reinitialize matrices": zero sketches +
-                # fold_in fresh projections, shape-static (no recompile)
-                sketch = _refresh_sketch(sketch)
-                log.info("rank change -> %d at step %d "
-                         "(projection refresh, epoch %d)",
-                         int(new_rank), step, int(sketch.epoch))
-            state = dataclasses.replace(state, adaptive=adaptive,
-                                        sketch=sketch)
+                # adaptive rank controller (per pseudo-epoch)
+                if (loop.steps_per_epoch and run.adaptive is not None
+                        and state.sketch is not None
+                        and (step + 1) % loop.steps_per_epoch == 0):
+                    adaptive, new_rank, changed = adaptive_step(
+                        state.adaptive, state.sketch.rank,
+                        jnp.asarray(metrics["loss"], jnp.float32),
+                        run.adaptive)
+                    sketch = dataclasses.replace(state.sketch,
+                                                 rank=new_rank)
+                    if bool(changed):
+                        # paper Alg. 1 "reinitialize matrices": zero
+                        # sketches + fold_in fresh projections,
+                        # shape-static (no recompile)
+                        sketch = _refresh_sketch(sketch)
+                        log.info("rank change -> %d at step %d "
+                                 "(projection refresh, epoch %d)",
+                                 int(new_rank), step, int(sketch.epoch))
+                    state = dataclasses.replace(state, adaptive=adaptive,
+                                                sketch=sketch)
 
-        history.append({"step": step, "time_s": dt, **metrics})
+            with span(spans, "loop/record"):
+                if done is not None:
+                    tlog.append(done)
+                spans["step"] = dt
+                entry = {"step": step, "time_s": dt, **metrics}
+                history.append(entry)
+                nodes, flags = {}, {}
+                if tlog is not None and state.sketch is not None \
+                        and step % loop.log_every == 0:
+                    # ring drain (one small device->host copy) only on
+                    # log steps — the per-step record stays scalars +
+                    # spans
+                    nodes, flags = monitor_report(
+                        state.monitor, sk_paths,
+                        int(2 * state.sketch.rank + 1))
+                if step % loop.log_every == 0:
+                    log.info("step %d loss %.4f grad_norm %.3f (%.3fs)",
+                             step, metrics["loss"], metrics["grad_norm"],
+                             dt)
+            with span(spans, "loop/ckpt"):
+                if loop.ckpt_every and (step + 1) % loop.ckpt_every == 0:
+                    ckpt.save_async(step + 1, persistable(state),
+                                    metadata=save_meta)
+
+        _close_entry(entry, spans, counter.lowerings - lowered,
+                     first=step == step0)
         if tlog is not None:
-            nodes, flags = {}, {}
-            if state.sketch is not None and step % loop.log_every == 0:
-                # ring drain (one small device->host copy) only on log
-                # steps — the per-step record stays scalars + spans
-                nodes, flags = monitor_report(
-                    state.monitor, sk_paths,
-                    int(2 * state.sketch.rank + 1))
-            tlog.append(TelemetryRecord(
-                kind="train", step=step, scalars=metrics,
-                nodes=nodes, flags=flags, spans={"step": dt},
+            done = TelemetryRecord(
+                kind="train", step=step,
+                scalars={**metrics, "compiles": entry["compiles"]},
+                nodes=nodes, flags=flags, spans=spans,
                 wire_bytes=plan["wire_bytes"],
                 collectives=plan["collectives"],
                 mesh=plan["mesh"],
-                per_axis_collectives=plan["per_axis"]))
-        if step % loop.log_every == 0:
-            log.info("step %d loss %.4f grad_norm %.3f (%.3fs)",
-                     step, metrics["loss"], metrics["grad_norm"], dt)
-        if loop.ckpt_every and (step + 1) % loop.ckpt_every == 0:
-            ckpt.save_async(step + 1, persistable(state),
-                            metadata=save_meta)
+                per_axis_collectives=plan["per_axis"])
 
     ckpt.wait()
     if loop.ckpt_every:
         ckpt.save(loop.num_steps, persistable(state), metadata=save_meta)
     if tlog is not None:
+        if done is not None:
+            tlog.append(done)
         tlog.close()
     return state, history
 
@@ -348,19 +400,34 @@ def run_training_sharded(cfg, run: RunConfig, loop: LoopConfig, mesh,
         step_fn = jax.jit(make_train_step(cfg, run))
         history = []
         step0 = int(state.step)
+        counter = compile_counter()
         for step in range(step0, loop.num_steps):
-            tokens, labels = host_batch(pipe, step)
-            t0 = time.time()
-            state, metrics = step_fn(state, {"tokens": tokens,
-                                             "labels": labels})
-            history.append({"step": step,
-                            "time_s": time.time() - t0,
-                            **{k: float(v) for k, v in metrics.items()}})
-            if step % loop.log_every == 0:
-                log.info("step %d loss %.4f", step,
-                         history[-1]["loss"])
-            if loop.ckpt_every and (step + 1) % loop.ckpt_every == 0:
-                ckpt.save_async(step + 1, state)
+            spans: dict = {}
+            lowered = counter.lowerings
+            with jax.profiler.StepTraceAnnotation(STEP_ANNOTATION,
+                                                  step_num=step):
+                with span(spans, "loop/feed"):
+                    tokens, labels = host_batch(pipe, step)
+                with span(spans, "loop/dispatch"):
+                    state, metrics = step_fn(state, {"tokens": tokens,
+                                                     "labels": labels})
+                with span(spans, "loop/sync"):
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                with span(spans, "loop/record"):
+                    spans["step"] = spans["loop/dispatch"] + \
+                        spans["loop/sync"]
+                    entry = {"step": step, "time_s": spans["step"],
+                             **metrics}
+                    history.append(entry)
+                    if step % loop.log_every == 0:
+                        log.info("step %d loss %.4f", step,
+                                 metrics["loss"])
+                with span(spans, "loop/ckpt"):
+                    if loop.ckpt_every and \
+                            (step + 1) % loop.ckpt_every == 0:
+                        ckpt.save_async(step + 1, state)
+            _close_entry(entry, spans, counter.lowerings - lowered,
+                         first=step == step0)
         ckpt.wait()
         if loop.ckpt_every:
             ckpt.save(loop.num_steps, state)

@@ -18,6 +18,7 @@ from repro.optim.compression import (
 from repro.optim.sketched_sgd import compress_grads_countsketch
 from repro.optim.schedule import warmup_cosine
 from repro.parallel.sharding import constrain
+from repro.telemetry import scope
 from repro.train.state import RunConfig, TrainState, finalize_run
 
 
@@ -500,8 +501,11 @@ def make_train_step(cfg: ArchConfig, run: RunConfig):
         pick = lambda n, o: jax.tree.map(
             lambda a, b: jnp.where(good, a, b), n, o)
         if run.nan_guard:
-            new_params = pick(new_params, state.params)
-            new_opt = pick(new_opt, state.opt)
+            # XLA fuses the optimizer's update into this select and
+            # names the fusion after it: the select shares its scope
+            with scope("adamw"):
+                new_params = pick(new_params, state.params)
+                new_opt = pick(new_opt, state.opt)
             if new_sketch is not None:
                 new_sketch = pick(new_sketch, state.sketch)
 

@@ -170,3 +170,40 @@ def test_compile_cache_placement(monkeypatch, tmp_path):
         assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_keys_carry_op_names(monkeypatch, tmp_path):
+    """Two programs that differ only in a named scope are two entries:
+    the trace of a cached executable names the scopes it was built
+    with."""
+    from jax._src import cache_key, compiler
+
+    from repro.launch import compile_cache
+
+    def key(name):
+        def f(x):
+            with jax.named_scope(name):
+                return x * 2 + 1
+        module = jax.jit(f).lower(jnp.ones(4)).compiler_ir()
+        return cache_key.get(module, np.array(jax.devices()[:1]),
+                             compiler.get_compile_options(1, 1),
+                             jax.devices()[0].client)
+
+    flags = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_hlo_source_file_canonicalization_regex")
+    before = {f: getattr(jax.config, f) for f in flags}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert key("attention") != key("adamw")
+        # source paths from the checkout's root: "tests/test_system.py"
+        text = jax.jit(lambda x: x + 1).lower(jnp.ones(4)).as_text(
+            debug_info=True)
+        assert '"tests/test_system.py"' in text
+        assert str(compile_cache.ROOT) not in text
+        jax.config.update(flags[0], False)
+        assert key("attention") == key("adamw")
+    finally:
+        for f, v in before.items():
+            jax.config.update(f, v)
