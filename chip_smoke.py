@@ -45,7 +45,7 @@ from repro.models.transformer import (  # noqa: E402
 )
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.sketches import ema_triple_update  # noqa: E402
-from repro.telemetry import compile_counter  # noqa: E402
+from repro.telemetry import attention_paths, compile_counter  # noqa: E402
 from repro.train.loop import LoopConfig, run_training  # noqa: E402
 from repro.train.state import RunConfig  # noqa: E402
 
@@ -184,6 +184,10 @@ def phase_train(cfg, counter, seed):
           f"step-0 loss {loss0} outside [ln V{LOSS_BAND[0]:+}, "
           f"ln V{LOSS_BAND[1]:+}] with ln V = {ln_v}")
     check(late == 0, f"{late} compilation(s) after the first step")
+    paths = attention_paths()
+    check(PLATFORM != "tpu" or (paths["kernel"] > 0 and paths["scan"] == 0),
+          f"the train step's attention did not trace the kernel only: "
+          f"{paths}")
     check(on_platform(state), "train state is not on the TPU")
     dev = jax.devices()[0]
     peak, hbm = peak_bytes(dev), hbm_bytes(dev)
@@ -198,7 +202,7 @@ def phase_train(cfg, counter, seed):
         programs_lowered=counter.lowerings,
         step_s_from_step2=[h["time_s"] for h in hist[2:]],
         compilations_after_step0=late, peak_bytes_in_use=peak,
-        hbm_bytes_limit=hbm)
+        hbm_bytes_limit=hbm, attention_paths=paths)
     # one node's triple for the reconstruction precision check
     name = sorted(state.sketch.nodes)[0]
     node = state.sketch.nodes[name]

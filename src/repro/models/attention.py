@@ -1,8 +1,9 @@
 """GQA attention: chunked-causal (train/prefill) + KV-cache decode.
 
-Train/prefill uses an online-softmax scan over KV chunks (flash-attention
-schedule expressed in XLA; the Pallas TPU kernel in kernels/flash_attention
-implements the same tiling for the hot path). Decode supports full caches
+Train/prefill takes its causal (or windowed) core from the splash
+flash-attention Pallas kernel on one TPU (forward, dq and dkv kernels;
+masked blocks skipped), and elsewhere from an online-softmax scan over
+KV chunks (the same schedule expressed in XLA). Decode supports full caches
 and ring-buffer windowed caches (SWA/local/global-fallback); when
 kv_heads < TP the cache is sequence-sharded over the model axis and XLA
 merges partial softmaxes (flash-decoding; explicit collective in
@@ -10,14 +11,16 @@ parallel/collectives.merge_partial_attn).
 """
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.models.layers import dense_init, rope
-from repro.parallel.sharding import constrain
-from repro.telemetry import scope
+from repro.parallel.sharding import constrain, current_rules
+from repro.telemetry import count_attention_path, scope
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -66,59 +69,134 @@ def chunked_causal_attention(
 ) -> Array:
     with scope("attention"):
         B, S, KV, G, D = q.shape
-        chunk = min(chunk, S)
-        assert S % chunk == 0, (S, chunk)
-        n_chunks = S // chunk
-        scale = D ** -0.5
-        qf = (q * scale).astype(q.dtype)
-        q_pos = jnp.arange(S)
+        qf = (q * D ** -0.5).astype(q.dtype)
+        if (kernel_block(S, D) and _platform() == "tpu"
+                and not _multi_device_mesh()):
+            count_attention_path("kernel")
+            return splash_causal_attention(qf, k, v, window=window)
+        count_attention_path("scan")
+        return _scan_causal_attention(qf, k, v, window=window, chunk=chunk)
 
-        m0 = jnp.full((B, KV, G, S), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, KV, G, S), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, S, D), jnp.float32)
 
-        def body(carry, j):
-            m, l, acc = carry
-            kj = jax.lax.dynamic_slice_in_dim(k, j * chunk, chunk,
-                                              axis=1)
-            vj = jax.lax.dynamic_slice_in_dim(v, j * chunk, chunk,
-                                              axis=1)
-            s = jnp.einsum(
-                "bqhgd,bkhd->bhgqk", qf, kj,
-                preferred_element_type=jnp.float32
-            )
-            # additive 2D mask (broadcast at the add): a boolean 5D mask
-            # gets hoisted/stacked by XLA's loop optimizer into a
-            # (n_chunks, B, ...) pred carry — hundreds of MB per layer.
-            # Keep it (S, chunk) f32.
-            k_pos = j * chunk + jnp.arange(chunk)
-            bias = jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0,
-                             NEG_INF)
-            if window is not None:
-                bias = bias + jnp.where(
-                    (q_pos[:, None] - k_pos[None, :]) < window, 0.0, NEG_INF)
-            s = s + bias[None, None, None]
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l_new = l * corr + p.sum(axis=-1)
-            acc_new = acc * corr[..., None] + jnp.einsum(
-                "bhgqk,bkhd->bhgqd", p.astype(v.dtype), vj,
-                preferred_element_type=jnp.float32,
-            )
-            return (m_new, l_new, acc_new), None
+def _platform() -> str:
+    return jax.default_backend()
 
-        # checkpoint the chunk body: the scan's VJP otherwise saves the
-        # (B,KV,G,S,chunk) softmax intermediates for every chunk —
-        # recomputing them in the backward sweep is the flash-attention
-        # trade.
-        body = jax.checkpoint(body, prevent_cse=False)
-        (m, l, acc), _ = jax.lax.scan(
-            body, (m0, l0, a0), jnp.arange(n_chunks)
+
+def _multi_device_mesh() -> bool:
+    """A mesh of more than one device: the sharding rules' (a jit under
+    `use_rules`), or the one a shard_map traces in (the data-parallel
+    step sets no rules). A `pallas_call` there would need a shard_map
+    of its own, so meshes keep the scan."""
+    rules = current_rules()
+    return ((rules is not None and rules.mesh.size > 1)
+            or jax.sharding.get_abstract_mesh().size > 1)
+
+
+def kernel_block(seq: int, head_dim: int) -> int | None:
+    """The splash kernel's block (q and kv, forward and backward) for a
+    sequence and head width, or None where the kernel does not take
+    them. 1024 from S = 8192 up, where 8 or more blocks a row keep the
+    masked halves of the diagonal blocks to an eighth of the causal work
+    or less, else 512 (timed on a v5e: PERF.md §3); clipped to the
+    sequence, which must be a whole number of blocks of whole 128-lane
+    tiles, for heads up to 256 wide."""
+    block = min(1024 if seq >= 8192 else 512, seq)
+    if head_dim > 256 or block % 128 or seq % block:
+        return None
+    return block
+
+
+@lru_cache(maxsize=None)
+def _splash_kernel(heads: int, kv_heads: int, seq: int,
+                   window: int | None, block: int, interpret: bool):
+    """One splash kernel per shape, window and block: its mask is
+    preprocessed on the host, once, not at every trace of the step."""
+    if window is None:
+        mask = splash.CausalMask((seq, seq))
+    else:  # q_pos - k_pos < window, and causal
+        mask = splash.LocalMask((seq, seq), window_size=(window - 1, 0),
+                                offset=0)
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    make = (splash.make_splash_mqa_single_device if kv_heads == 1
+            else splash.make_splash_mha_single_device)
+    # the mask's block tables become constants, not tracers of the
+    # trace that first builds the kernel
+    with jax.ensure_compile_time_eval():
+        return make(splash.MultiHeadMask([mask] * heads),
+                    block_sizes=sizes, interpret=interpret)
+
+
+def splash_causal_attention(qf: Array, k: Array, v: Array, *,
+                            window: int | None) -> Array:
+    """Causal attention of pre-scaled grouped queries on the splash
+    kernel (interpreted off the TPU), vmapped over the batch; (B, S, KV,
+    G, D) out in q's dtype. Query head kv·G + g reads KV head kv (the
+    kernel's h // G)."""
+    B, S, KV, G, D = qf.shape
+    kernel = _splash_kernel(KV * G, KV, S, window, kernel_block(S, D),
+                            _platform() != "tpu")
+    qh = qf.reshape(B, S, KV * G, D).transpose(0, 2, 1, 3)
+    kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    if KV == 1:
+        kh, vh = kh[:, 0], vh[:, 0]
+    out = jax.vmap(kernel)(qh, kh, vh)           # (B, KV*G, S, D)
+    return out.transpose(0, 2, 1, 3).reshape(B, S, KV, G, D).astype(
+        qf.dtype)
+
+
+def _scan_causal_attention(qf: Array, k: Array, v: Array, *,
+                           window: int | None, chunk: int) -> Array:
+    """Online-softmax scan over KV chunks of pre-scaled queries."""
+    B, S, KV, G, D = qf.shape
+    chunk = min(chunk, S)
+    assert S % chunk == 0, (S, chunk)
+    n_chunks = S // chunk
+    q_pos = jnp.arange(S)
+
+    m0 = jnp.full((B, KV, G, S), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((B, KV, G, S), jnp.float32)
+    a0 = jnp.zeros((B, KV, G, S, D), jnp.float32)
+
+    def body(carry, j):
+        m, l, acc = carry
+        kj = jax.lax.dynamic_slice_in_dim(k, j * chunk, chunk, axis=1)
+        vj = jax.lax.dynamic_slice_in_dim(v, j * chunk, chunk, axis=1)
+        s = jnp.einsum(
+            "bqhgd,bkhd->bhgqk", qf, kj,
+            preferred_element_type=jnp.float32
         )
-        out = acc / jnp.maximum(l, 1e-30)[..., None]
-        # (B,KV,G,S,D) -> (B,S,KV,G,D)
-        return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)
+        # additive 2D mask (broadcast at the add): a boolean 5D mask
+        # gets hoisted/stacked by XLA's loop optimizer into a
+        # (n_chunks, B, ...) pred carry — hundreds of MB per layer.
+        # Keep it (S, chunk) f32.
+        k_pos = j * chunk + jnp.arange(chunk)
+        bias = jnp.where(q_pos[:, None] >= k_pos[None, :], 0.0, NEG_INF)
+        if window is not None:
+            bias = bias + jnp.where(
+                (q_pos[:, None] - k_pos[None, :]) < window, 0.0, NEG_INF)
+        s = s + bias[None, None, None]
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        l_new = l * corr + p.sum(axis=-1)
+        acc_new = acc * corr[..., None] + jnp.einsum(
+            "bhgqk,bkhd->bhgqd", p.astype(v.dtype), vj,
+            preferred_element_type=jnp.float32,
+        )
+        return (m_new, l_new, acc_new), None
+
+    # checkpoint the chunk body: the scan's VJP otherwise saves the
+    # (B,KV,G,S,chunk) softmax intermediates for every chunk —
+    # recomputing them in the backward sweep is the flash-attention
+    # trade.
+    body = jax.checkpoint(body, prevent_cse=False)
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(n_chunks))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    # (B,KV,G,S,D) -> (B,S,KV,G,D)
+    return out.transpose(0, 3, 1, 2, 4).astype(qf.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -239,8 +317,6 @@ def attn_apply(
 
 def _constrain_cache(cache: dict, cfg) -> dict:
     """Cache layout: kv-head sharded when KV >= TP else sequence-sharded."""
-    from repro.parallel.sharding import current_rules
-
     rules = current_rules()
     tp = rules.tp_size if rules is not None else 1
 

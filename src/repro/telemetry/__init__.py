@@ -6,8 +6,8 @@ the hot path stays jit-pure and recompile-free), and the host drains it
 into ``TelemetryRecord``s exported as JSONL. ``run_metadata`` is the
 shared attribution header for telemetry logs and the BENCH_*.json
 baselines. ``instrument`` holds the names a profiler trace shows (the
-device scopes, the loop's host spans) and the process's compile
-counter.
+device scopes, the loop's host spans), the process's compile counter
+and its count of attention traces by path.
 """
 from repro.telemetry.schema import (
     RECORD_KINDS, SCHEMA_VERSION, TelemetryRecord, record_from_json,
@@ -18,12 +18,14 @@ from repro.telemetry.collector import (
     flag_paths, latest_reading, monitor_report, node_metrics, span,
 )
 from repro.telemetry.instrument import (
-    LOOP_SPANS, SCOPES, STEP_ANNOTATION, compile_counter, scope,
+    ATTENTION_PATHS, LOOP_SPANS, SCOPES, STEP_ANNOTATION, attention_paths,
+    compile_counter, count_attention_path, scope,
 )
 
 __all__ = [
-    "LOOP_SPANS", "RECORD_KINDS", "SCHEMA_VERSION", "SCOPES",
-    "STEP_ANNOTATION", "TelemetryLog", "TelemetryRecord", "compile_counter",
+    "ATTENTION_PATHS", "LOOP_SPANS", "RECORD_KINDS", "SCHEMA_VERSION",
+    "SCOPES", "STEP_ANNOTATION", "TelemetryLog", "TelemetryRecord",
+    "attention_paths", "compile_counter", "count_attention_path",
     "flag_paths", "latest_reading", "monitor_report", "node_metrics",
     "read_jsonl", "record_from_json", "record_to_json", "record_to_line",
     "run_metadata", "scalarize", "scope", "span",

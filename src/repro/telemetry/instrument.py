@@ -16,7 +16,9 @@ These names are a contract: the benchmark's readers find them in a
 profiler trace by name, so a rename here needs the same rename there.
 
 The compile counter is one process-wide ``jax.monitoring`` listener,
-registered on first use.
+registered on first use. The attention path count says which core
+each trace of ``chunked_causal_attention`` took: the flash kernel or
+the XLA chunk scan.
 """
 from __future__ import annotations
 
@@ -29,6 +31,9 @@ LOOP_SPANS = ("loop/feed", "loop/dispatch", "loop/sync", "loop/control",
               "loop/record", "loop/ckpt")
 # the name of each step's StepTraceAnnotation
 STEP_ANNOTATION = "train"
+
+# the cores ``chunked_causal_attention`` may trace
+ATTENTION_PATHS = ("kernel", "scan")
 
 _LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -70,3 +75,17 @@ def compile_counter() -> CompileCounter:
         _counter = CompileCounter()
         jax.monitoring.register_event_duration_secs_listener(_counter)
     return _counter
+
+
+_attention_paths = dict.fromkeys(ATTENTION_PATHS, 0)
+
+
+def count_attention_path(path: str) -> None:
+    """Count one trace of ``chunked_causal_attention`` on ``path``."""
+    _attention_paths[path] += 1
+
+
+def attention_paths() -> dict:
+    """Traces of ``chunked_causal_attention`` in this process, by the
+    path each took: ``{"kernel": n, "scan": m}``."""
+    return dict(_attention_paths)

@@ -15,7 +15,8 @@ import pytest
 from repro.configs import get_arch, reduced
 from repro.models.transformer import SketchSettings
 from repro.telemetry import (
-    LOOP_SPANS, SCOPES, compile_counter, read_jsonl, scope, span,
+    ATTENTION_PATHS, LOOP_SPANS, SCOPES, attention_paths, compile_counter,
+    read_jsonl, scope, span,
 )
 from repro.train.state import RunConfig, init_train_state
 
@@ -101,6 +102,23 @@ def test_compile_counter_counts_a_forced_lowering():
     jax.clear_caches()               # the same call, lowered again
     f(x3)
     assert c.lowerings == n + 3
+
+
+def test_attention_path_counter_counts_traces():
+    """One trace of the attention on the CPU counts one `scan`; calling
+    the jitted function again traces nothing and counts nothing."""
+    from repro.models.attention import chunked_causal_attention
+
+    assert tuple(attention_paths()) == ATTENTION_PATHS
+    f = jax.jit(lambda q, k, v: chunked_causal_attention(
+        q, k, v, window=None))
+    q, kv = jnp.ones((1, 128, 1, 2, 8)), jnp.ones((1, 128, 1, 8))
+    before = attention_paths()
+    f(q, kv, kv)
+    f(q, kv, kv)
+    after = attention_paths()
+    assert (after["scan"] - before["scan"],
+            after["kernel"] - before["kernel"]) == (1, 0)
 
 
 def test_span_reaches_the_profiler_timeline(tmp_path):
